@@ -274,6 +274,7 @@ def serve():
     import numpy as np
 
     from repro.configs.base import get_config, reduce_config
+    from repro.launch.mesh import make_mesh
     from repro.models.registry import build_model
     from repro.serve.engine import Request, ServeEngine
 
@@ -285,7 +286,7 @@ def serve():
                 f"--mesh tp={tp} needs {tp} devices but jax sees "
                 f"{jax.device_count()} (run.py forces the host platform "
                 "count only when jax is not already initialized)")
-        mesh = jax.make_mesh((tp,), ("model",))
+        mesh = make_mesh((tp,), ("model",))
     # d_model=256 gives 8 heads / d_ff 768: dims an 8-way axis divides
     cfg = reduce_config(get_config("qwen2-1.5b"), layers=2,
                         d_model=(256 if mesh is not None else 64),
@@ -779,6 +780,8 @@ def main() -> None:
         todo.append("kvcache")
     if args.spec and "spec" not in todo:
         todo.append("spec")
+    from repro import backend
+    backend.enable_compile_cache()      # after any forced-device XLA_FLAGS
     print("name,us_per_call,derived")
     for name in todo:
         TABLES[name]()

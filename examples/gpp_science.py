@@ -9,6 +9,7 @@ reproduction (EXPERIMENTS.md §Perf/GPP).
 
 import argparse
 
+from repro import backend
 from repro.core.journey import format_journey, run_journey, sweep_blocks
 
 
@@ -22,6 +23,7 @@ def main():
     ap.add_argument("--no-cpu", action="store_true",
                     help="skip CPU wall-clock measurements")
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     rows = run_journey(args.size, measure_cpu=not args.no_cpu)
     print()

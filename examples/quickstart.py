@@ -5,11 +5,12 @@ steps on CPU, and sample from it. Runs in ~1 minute.
     PYTHONPATH=src python examples/quickstart.py
 """
 
-import jax
 import numpy as np
 
 import repro
+from repro import backend
 from repro.configs.base import get_config, reduce_config
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import TrainLoopConfig, Trainer
 
 
@@ -28,6 +29,7 @@ def kernel_demo():
 
 
 def main():
+    backend.enable_compile_cache()
     kernel_demo()
 
     cfg = reduce_config(get_config("qwen2-1.5b"), layers=2, d_model=128,
@@ -35,7 +37,7 @@ def main():
     loop = TrainLoopConfig(total_steps=20, ckpt_every=10, log_every=5,
                            ckpt_dir="runs/quickstart_ckpt", seq_len=64,
                            global_batch=4, peak_lr=1e-3)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     trainer = Trainer(cfg, loop, mesh)
     out = trainer.run()
     if out["final_loss"] is None:

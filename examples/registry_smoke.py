@@ -12,6 +12,7 @@ kernel actually joined the dispatch/tune/bench plumbing.
 import numpy as np
 
 import repro
+from repro import backend
 from repro.kernels import api
 
 
@@ -23,6 +24,7 @@ def check(name: str, outs, refs, atol: float) -> None:
 
 
 def main():
+    backend.enable_compile_cache()
     names = repro.list_kernels()
     print(f"registered kernels: {names}")
     assert {"gpp", "flash", "ssm", "paged_decode"} <= set(names), names

@@ -18,7 +18,9 @@ import time
 import jax
 import numpy as np
 
+from repro import backend
 from repro.configs.base import get_config, reduce_config
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -33,12 +35,13 @@ def main():
                     help="serve tensor-parallel over a tp-way model axis "
                          "(needs >= tp jax devices)")
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     cfg = reduce_config(get_config("phi4-mini-3.8b"), layers=4, d_model=256,
                         vocab=1024)
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))
-    mesh = (jax.make_mesh((args.tp,), ("model",)) if args.tp > 1 else None)
+    mesh = (make_mesh((args.tp,), ("model",)) if args.tp > 1 else None)
     engine = ServeEngine(cfg, params, max_batch=args.batch, mesh=mesh)
 
     rng = np.random.default_rng(0)

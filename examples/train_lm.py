@@ -14,11 +14,10 @@ step (mesh via flags) -> prefetching data pipeline -> checkpoint/auto-resume
 """
 
 import argparse
-import dataclasses
 
-import jax
-
+from repro import backend
 from repro.configs.base import ModelConfig
+from repro.launch.mesh import make_mesh
 from repro.train.trainer import TrainLoopConfig, Trainer
 
 PRESETS = {
@@ -43,6 +42,7 @@ def main():
     ap.add_argument("--token-file", default=None,
                     help="flat binary token file (default: synthetic)")
     args = ap.parse_args()
+    backend.enable_compile_cache()
 
     p = PRESETS[args.preset]
     cfg = ModelConfig(
@@ -57,7 +57,7 @@ def main():
         ckpt_dir=args.ckpt_dir or f"runs/train_lm_{args.preset}",
         seq_len=p["seq_len"], global_batch=p["batch"], peak_lr=args.lr,
         token_file=args.token_file)
-    mesh = jax.make_mesh((args.data, args.model), ("data", "model"))
+    mesh = make_mesh((args.data, args.model), ("data", "model"))
     out = Trainer(cfg, loop, mesh).run()
     if out["final_loss"] is None:
         print(f"nothing to do: checkpoint already at step {out['start_step']}")

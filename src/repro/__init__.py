@@ -50,8 +50,6 @@ carries a full docstring with a runnable example at its definition —
     rows = repro.run_journey("si214")
 """
 
-from repro import _compat  # noqa: F401  (jax API shims; must import first)
-
 # public name -> defining module; resolved lazily on first access so that
 # `import repro` stays cheap and optional layers never import eagerly
 _EXPORTS = {

@@ -231,14 +231,16 @@ def _walk(jaxpr, scale: float, out: JaxprCensus) -> None:
             out.grid_instances += int(grid * scale)
             vmem = 0
             for bm in getattr(gm, "block_mappings", ()) or ():
-                sd = getattr(bm, "array_shape_dtype", None)
-                blk = [d for d in getattr(bm, "block_shape", ())
-                       if isinstance(d, int)]
-                if sd is not None and blk:
+                # block dims are Blocked(block_size=n); Squeezed dims hold
+                # one element and are skipped
+                blk = [d.block_size for d in bm.block_shape
+                       if hasattr(d, "block_size")]
+                if blk:
                     n = 1
                     for d in blk:
                         n *= d
-                    vmem += 2 * n * np.dtype(sd.dtype).itemsize  # dbl-buffer
+                    itemsize = np.dtype(bm.array_aval.dtype).itemsize
+                    vmem += 2 * n * itemsize               # dbl-buffer
             out.vmem_block_bytes = max(out.vmem_block_bytes, vmem)
             _walk(eqn.params["jaxpr"], scale * grid, out)
             continue

@@ -9,6 +9,11 @@ entry point now resolves its `interpret` default through this module.
 Env override: `REPRO_INTERPRET=1` forces interpret mode even on TPU (kernel
 debugging), `REPRO_INTERPRET=0` forces compiled mode (fails fast on CPU
 rather than silently interpreting). Unset: autodetect (interpret iff no TPU).
+A JAX that cannot initialize its backend raises here; it is never read as
+"no TPU".
+
+The module also places JAX's persistent compilation cache for the entry
+points (`enable_compile_cache`).
 """
 
 from __future__ import annotations
@@ -17,23 +22,34 @@ import os
 from typing import Optional
 
 INTERPRET_ENV = "REPRO_INTERPRET"
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <repo root>/.jax_cache: a fixed path, because the cache key includes it
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
 
 
 def backend_name() -> str:
-    """jax.default_backend(), with a safe fallback when jax can't init."""
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    import jax
+    return jax.default_backend()
 
 
 def on_tpu() -> bool:
-    try:
-        import jax
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    import jax
+    return jax.devices()[0].platform == "tpu"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. Where $JAX_COMPILATION_CACHE_DIR is set, JAX
+    reads it and nothing is set here; otherwise the cache goes to
+    <repo root>/.jax_cache."""
+    env = os.environ.get(CACHE_ENV)
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", REPO_CACHE_DIR)
+    return REPO_CACHE_DIR
 
 
 def default_interpret() -> bool:

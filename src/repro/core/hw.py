@@ -60,7 +60,9 @@ TPU_V5E = HardwareSpec(
     vpu_flops=7.7e12,
     hbm_bw=819e9,
     ici_bw=50e9,
-    vmem_bytes=16 * 2**20,  # ~16 MiB usable scratch half? full VMEM budget
+    # the scoped-VMEM limit Mosaic gives one Pallas kernel by default on
+    # v5e (the chip has 128 MiB of VMEM; a kernel may raise its own limit)
+    vmem_bytes=16 * 2**20,
     hbm_bytes=16 * 2**30,
 )
 

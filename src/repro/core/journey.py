@@ -4,9 +4,10 @@ beyond the paper: v9 (fused VMEM scratch accumulation + parallel grid
 semantics) and v10 (v9 under the repro.tune autotuner's per-size pick).
 
 Per version v0..v10 this harness reports:
-  * correctness vs the complex128 oracle (TINY problem, CPU);
-  * measured CPU wall-clock at BENCH size (secondary signal — the container
-    is CPU-only; the pure-JAX variants really execute, Pallas in interpret);
+  * correctness vs the complex128 oracle at TINY (the Pallas versions run
+    compiled on a TPU and interpreted elsewhere, as repro.backend decides);
+  * host wall-clock of the pure-JAX variants at BENCH size (measure_cpu;
+    a CPU-run number — the Pallas versions are not timed here);
   * the modeled v5e roofline: VPU compute seconds from an instruction-class
     census (mul/add=1 pass, rcp=4, sqrt=8, div=8 — the TPU analogue of the
     paper's instruction-latency ledger), HBM seconds from each version's
@@ -143,21 +144,14 @@ def _run_version(version: str, inputs_bench, inputs_tiny, ref_tiny,
     if version in variants.VARIANTS:
         fn = kernel_def.jitted_variant(version)   # cached — no re-jit
         runner = lambda x: fn(x)
-    else:
-        cfg = pallas_gpp.CONFIGS.get(version, pallas_gpp.V9)
-
-        def runner(x):
-            return api.dispatch("gpp", x, version=version, config=cfg,
-                                interpret=True)
-
-    # correctness at TINY (pallas configs need divisibility: use tiny cfg)
-    if version in variants.VARIANTS:
         a, x = runner(inputs_tiny)
     else:
-        base = pallas_gpp.CONFIGS.get(version, pallas_gpp.V9)
-        tiny_cfg = dataclasses.replace(base, blk_ig=32, blk_igp=4, blk_band=4)
+        # correctness at TINY: the version's blocks clamped to the problem
+        # (whole-dim blocks, which Mosaic tiles for every version);
+        # compiled or interpreted as repro.backend decides
+        cfg = pallas_gpp.CONFIGS.get(version, pallas_gpp.V9)
         a, x = api.dispatch("gpp", inputs_tiny, version=version,
-                            config=tiny_cfg, interpret=True)
+                            config=cfg.clamped(problem.TINY))
     ar, xr = ref_tiny
     rel = max(
         float(np.max(np.abs(np.asarray(a) - ar)) / np.max(np.abs(ar))),
@@ -217,7 +211,12 @@ def run_journey(size_name: str = "si214", *, measure_cpu: bool = True,
         rel, cpu_ms = _run_version(v, inputs_bench, inputs_tiny, ref_tiny,
                                    measure_cpu=measure_cpu)
         rep = _model_report(v, size)
-        rows.append(JourneyRow(v, cpu_ms, rel, rep, NOTES[v]))
+        note = NOTES[v]
+        cfg = pallas_gpp.CONFIGS.get(v)
+        if cfg is not None and not cfg.mosaic_tileable(size):
+            note += f"; not compiled on the chip at {size.name}: Mosaic " \
+                    "cannot tile its blocks (modeled only)"
+        rows.append(JourneyRow(v, cpu_ms, rel, rep, note))
         if verbose:
             r = rows[-1]
             print(f"{v}: err={rel:.1e} cpu={cpu_ms and f'{cpu_ms:.1f}ms'} "

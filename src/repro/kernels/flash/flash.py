@@ -26,10 +26,13 @@ oracle) in interpret mode by tests/test_flash_kernel.py.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro import backend
 
 NEG_INF = -1e30
 
@@ -77,10 +80,12 @@ def _kernel(q_ref, k_ref, v_ref, acc_ref, l_ref, m_ref, *,
 
 
 def flash_attention_bhsd(q, k, v, *, blk_q: int = 256, blk_kv: int = 256,
-                         causal: bool = True, interpret: bool = True
-                         ) -> jax.Array:
+                         causal: bool = True,
+                         interpret: Optional[bool] = None) -> jax.Array:
     """q: (BH, S, Hd); k/v: (BKvH, S, Hd) with BH = BKvH * group.
-    Returns (BH, S, Hd) f32-accurate attention output (cast to q.dtype)."""
+    Returns (BH, S, Hd) f32-accurate attention output (cast to q.dtype).
+    interpret=None defers to repro.backend (REPRO_INTERPRET override)."""
+    interpret = backend.resolve_interpret(interpret)
     bh, sq, hd = q.shape
     bkv, skv, _ = k.shape
     group = bh // bkv
@@ -218,6 +223,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _fwd_with_stats(q, k, v, blk_q, blk_kv, causal, interpret):
+    interpret = backend.resolve_interpret(interpret)
     bh, sq, hd = q.shape
     bkv, skv, _ = k.shape
     group = bh // bkv
@@ -252,7 +258,7 @@ def _fwd_with_stats(q, k, v, blk_q, blk_kv, causal, interpret):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention_diff(q, k, v, blk_q=256, blk_kv=256, causal=True,
-                         interpret=True):
+                         interpret=None):
     out, _ = _fwd_with_stats(q, k, v, blk_q, blk_kv, causal, interpret)
     return out
 
@@ -263,6 +269,7 @@ def _flash_fwd(q, k, v, blk_q, blk_kv, causal, interpret):
 
 
 def _flash_bwd(blk_q, blk_kv, causal, interpret, res, dout):
+    interpret = backend.resolve_interpret(interpret)
     q, k, v, out, lse = res
     bh, sq, hd = q.shape
     bkv, skv, _ = k.shape

@@ -37,17 +37,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax>=0.5 renames TPUCompilerParams -> CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
+from repro import backend
 from repro.kernels.gpp.problem import LIMITONE, LIMITTWO, TOL_ZERO, GppSize
 
 
@@ -67,11 +64,29 @@ class BlockConfig:
         inputs += 2 * self.blk_band * self.blk_ig * 4         # aqsn tile
         inputs += 2 * self.blk_band * self.blk_igp * 4        # aqsm tile
         inputs += self.blk_band * nw * 4 + self.blk_ig * 4    # wx, vcoul
-        live = 14 * t_ig_igp                                  # intermediates
+        # live (BLK_IG, BLK_IGP) f32 intermediates of the band sweep: 24
+        # planes matches Mosaic's scoped-VMEM allocation on v5e, which
+        # refuses every si214 config with 0.5 MiB planes under the 16 MiB
+        # limit and accepts every one with 0.25 MiB planes
+        live = 24 * t_ig_igp
         total = 2 * inputs + live
         if self.fused_acc:
-            total += 4 * nw * 4                               # scratch accs
+            total += OUT_TILE[0] * OUT_TILE[1] * 4            # scratch tile
         return total
+
+    def mosaic_tileable(self, size: GppSize) -> bool:
+        """Whether Mosaic (the TPU kernel compiler) accepts these blocks:
+        the last two dims of every block must be multiples of (8, 128) or
+        span the whole array dim. ig and igp are lane dims (wtilde tile,
+        aqsn tile), band a sublane dim (aqsn/wx tiles) — except in v6's
+        untransposed aqsm tile (blk_igp, blk_band), where band is the lane
+        dim: that is the layout the paper's index swap (v7) repairs."""
+        def ok(blk, full, mult):
+            return blk == full or blk % mult == 0
+        lanes_band = not self.aqsm_transposed
+        return (ok(self.blk_ig, size.ncouls, 128)
+                and ok(self.blk_igp, size.ngpown, 128)
+                and ok(self.blk_band, size.nbands, 128 if lanes_band else 8))
 
     def clamped(self, size: GppSize) -> "BlockConfig":
         """Shrink blocks to fit a smaller problem (power-of-two dims keep
@@ -96,6 +111,9 @@ V9 = BlockConfig("v9", blk_ig=512, blk_igp=128, blk_band=32,
                  aqsm_transposed=True, fused_acc=True)
 
 CONFIGS = {"v6": V6, "v7": V7, "v8": V8, "v9": V9}
+
+# one grid instance's output block: an (8, 128) f32 vreg (see _acc_tile)
+OUT_TILE = (8, 128)
 
 
 def _band_sweep(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
@@ -189,41 +207,43 @@ def _band_sweep(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
     return jax.lax.fori_loop(0, cfg.blk_band, band_iter, init)
 
 
+def _acc_tile(accs, nw: int):
+    """Pack the per-iw (ach_re, ach_im, asx_re, asx_im) scalars into one
+    (8, 128) f32 tile: row r holds quantity r, lane iw holds frequency iw.
+    One full vreg is the smallest output block Mosaic tiles, and a vector
+    store replaces 4*nw scalar stores."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, OUT_TILE, 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, OUT_TILE, 1)
+    tile = jnp.zeros(OUT_TILE, jnp.float32)
+    for iw in range(nw):
+        for r, v in enumerate(accs[iw]):
+            tile = jnp.where((rows == r) & (lanes == iw), v, tile)
+    return tile
+
+
 def _kernel(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
             aqsn_re_ref, aqsn_im_ref, aqsm_re_ref, aqsm_im_ref,
-            wx_ref, vcoul_ref,
-            ach_re_ref, ach_im_ref, asx_re_ref, asx_im_ref,
-            *, cfg: BlockConfig, nw: int):
-    """v6–v8: the output block is revisited across band steps and
+            wx_ref, vcoul_ref, out_ref, *, cfg: BlockConfig, nw: int):
+    """v6–v8: the output tile is revisited across band steps and
     read-modify-written in place (@pl.when(band_step == 0) zero-init)."""
     band_step = pl.program_id(2)
 
     @pl.when(band_step == 0)
     def _init():
-        ach_re_ref[...] = jnp.zeros_like(ach_re_ref)
-        ach_im_ref[...] = jnp.zeros_like(ach_im_ref)
-        asx_re_ref[...] = jnp.zeros_like(asx_re_ref)
-        asx_im_ref[...] = jnp.zeros_like(asx_im_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
     accs = _band_sweep(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
                        aqsn_re_ref, aqsn_im_ref, aqsm_re_ref, aqsm_im_ref,
                        wx_ref, vcoul_ref, cfg=cfg, nw=nw)
-    for iw in range(nw):
-        a_re, a_im, x_re, x_im = accs[iw]
-        ach_re_ref[0, 0, iw] += a_re
-        ach_im_ref[0, 0, iw] += a_im
-        asx_re_ref[0, 0, iw] += x_re
-        asx_im_ref[0, 0, iw] += x_im
+    out_ref[...] += _acc_tile(accs, nw)
 
 
 def _kernel_fused(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
                   aqsn_re_ref, aqsn_im_ref, aqsm_re_ref, aqsm_im_ref,
-                  wx_ref, vcoul_ref,
-                  ach_re_ref, ach_im_ref, asx_re_ref, asx_im_ref,
-                  acc_ref,
+                  wx_ref, vcoul_ref, out_ref, acc_ref,
                   *, cfg: BlockConfig, nw: int):
-    """v9: partial sums accumulate in a (4, nw) VMEM scratch across the
-    band steps; the output block is written exactly once, on the last
+    """v9: partial sums accumulate in an (8, 128) VMEM scratch tile across
+    the band steps; the output tile is written exactly once, on the last
     step. No output RMW per band step -> igp/ig can be `parallel`."""
     band_step = pl.program_id(2)
 
@@ -234,29 +254,24 @@ def _kernel_fused(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
     accs = _band_sweep(wt_re_ref, wt_im_ref, eps_re_ref, eps_im_ref,
                        aqsn_re_ref, aqsn_im_ref, aqsm_re_ref, aqsm_im_ref,
                        wx_ref, vcoul_ref, cfg=cfg, nw=nw)
-    for iw in range(nw):
-        a_re, a_im, x_re, x_im = accs[iw]
-        acc_ref[0, iw] += a_re
-        acc_ref[1, iw] += a_im
-        acc_ref[2, iw] += x_re
-        acc_ref[3, iw] += x_im
+    acc_ref[...] += _acc_tile(accs, nw)
 
     @pl.when(band_step == pl.num_programs(2) - 1)
     def _flush():
-        for iw in range(nw):
-            ach_re_ref[0, 0, iw] = acc_ref[0, iw]
-            ach_im_ref[0, 0, iw] = acc_ref[1, iw]
-            asx_re_ref[0, 0, iw] = acc_ref[2, iw]
-            asx_im_ref[0, 0, iw] = acc_ref[3, iw]
+        out_ref[...] = acc_ref[...]
 
 
 def gpp_pallas(inputs: Dict, cfg: BlockConfig, *,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               interpret: Optional[bool] = None
+               ) -> Tuple[jax.Array, jax.Array]:
     """Run the blocked GPP kernel. inputs: planar dict (problem.make_inputs).
-    Returns (ach (nw,) complex64, asx (nw,) complex64)."""
+    Returns (ach (nw,) complex64, asx (nw,) complex64).
+    interpret=None defers to repro.backend (REPRO_INTERPRET override)."""
+    interpret = backend.resolve_interpret(interpret)
     f = {k: jnp.asarray(v, jnp.float32) for k, v in inputs.items()}
     ncouls, ngpown = f["wtilde_re"].shape
     nw, nbands = f["wx"].shape
+    assert nw <= OUT_TILE[1], nw
 
     assert ncouls % cfg.blk_ig == 0, (ncouls, cfg.blk_ig)
     assert ngpown % cfg.blk_igp == 0, (ngpown, cfg.blk_igp)
@@ -284,35 +299,37 @@ def gpp_pallas(inputs: Dict, cfg: BlockConfig, *,
     aqsn_spec = pl.BlockSpec((cfg.blk_band, cfg.blk_ig), lambda i, j, b: (b, j))
     wx_spec = pl.BlockSpec((cfg.blk_band, nw), lambda i, j, b: (b, 0))
     vc_spec = pl.BlockSpec((cfg.blk_ig, 1), lambda i, j, b: (j, 0))
-    out_spec = pl.BlockSpec((1, 1, nw), lambda i, j, b: (i, j, 0))
-    out_shape = jax.ShapeDtypeStruct((n_igp, n_ig, nw), jnp.float32)
+    # squeezed (igp, ig) block dims: the kernel sees one (8, 128) tile
+    out_spec = pl.BlockSpec((None, None) + OUT_TILE,
+                            lambda i, j, b: (i, j, 0, 0))
+    out_shape = jax.ShapeDtypeStruct((n_igp, n_ig) + OUT_TILE, jnp.float32)
 
     extra = {}
     if cfg.fused_acc:
         kern = functools.partial(_kernel_fused, cfg=cfg, nw=nw)
-        extra["scratch_shapes"] = [pltpu.VMEM((4, nw), jnp.float32)]
+        extra["scratch_shapes"] = [pltpu.VMEM(OUT_TILE, jnp.float32)]
         # the band axis carries the scratch accumulator -> arbitrary; the
         # igp/ig axes have no cross-instance state -> parallel
-        extra["compiler_params"] = _CompilerParams(
+        extra["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"))
     else:
         kern = functools.partial(_kernel, cfg=cfg, nw=nw)
-    outs = pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=(n_igp, n_ig, n_b),
         in_specs=[ig_igp, ig_igp, ig_igp, ig_igp,
                   aqsn_spec, aqsn_spec, aqsm_spec, aqsm_spec,
                   wx_spec, vc_spec],
-        out_specs=[out_spec, out_spec, out_spec, out_spec],
-        out_shape=[out_shape] * 4,
+        out_specs=out_spec,
+        out_shape=out_shape,
         interpret=interpret,
         **extra,
     )(f["wtilde_re"], f["wtilde_im"], f["eps_re"], f["eps_im"],
       aqsn_re, aqsn_im, aqsm_re, aqsm_im, wx, vcoul)
 
-    ach_re, ach_im, asx_re, asx_im = outs
-    ach = jnp.sum(ach_re, (0, 1)) + 1j * jnp.sum(ach_im, (0, 1))
-    asx = jnp.sum(asx_re, (0, 1)) + 1j * jnp.sum(asx_im, (0, 1))
+    tot = jnp.sum(out, (0, 1))[:4, :nw]          # rows: ach re/im, asx re/im
+    ach = tot[0] + 1j * tot[1]
+    asx = tot[2] + 1j * tot[3]
     return ach.astype(jnp.complex64), asx.astype(jnp.complex64)
 
 
@@ -322,7 +339,7 @@ def hbm_traffic_model(size: GppSize, cfg: BlockConfig) -> float:
       wtilde/eps: fetched once per (igp, ig) block  -> full arrays once
       aqsn: index (ig, band) — refetched per igp block
       aqsm: index (igp, band) — refetched per ig block
-      wx/vcoul/outs: negligible (counted anyway)
+      wx/vcoul/output tiles: negligible (counted anyway)
     """
     n_ig = size.ncouls // cfg.blk_ig
     n_igp = size.ngpown // cfg.blk_igp
@@ -332,5 +349,5 @@ def hbm_traffic_model(size: GppSize, cfg: BlockConfig) -> float:
     b += n_ig * 2 * 4 * size.ngpown * size.nbands          # aqsm
     b += n_ig * n_igp * 4 * size.nw * size.nbands          # wx
     b += n_igp * 4 * size.ncouls                           # vcoul
-    b += 4 * 4 * n_ig * n_igp * size.nw                    # outputs
+    b += 4 * n_ig * n_igp * OUT_TILE[0] * OUT_TILE[1]      # output tiles
     return b

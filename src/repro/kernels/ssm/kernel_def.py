@@ -35,7 +35,8 @@ from repro.core.vpu_model import GRID_OVERHEAD_S, PASS_RATE
 from repro.kernels import api
 from repro.kernels.ssm import ssm_scan as scan_lib
 
-BLK_C_MENU = (4, 8, 16, 32, 64, 128, 256, 512)
+# channel blocks are lane dims: multiples of 128, or (clamped) the whole C
+BLK_C_MENU = (128, 256, 512)
 LOOP_STEP_OVERHEAD_S = 0.02e-6     # per fori-loop iteration (sequencing)
 
 
@@ -51,22 +52,19 @@ class SsmKey:
         return f"{self.b}x{self.t}x{self.c}x{self.n}"
 
 
-def _div_clamp(blk: int, c: int) -> int:
-    """Largest block <= blk that exactly tiles c (the kernel asserts
-    divisibility — a plain min() clamp would crash on e.g. c=130)."""
-    blk = min(blk, c)
-    while c % blk:
-        blk -= 1
-    return blk
-
-
 @dataclasses.dataclass(frozen=True)
 class SsmScanConfig:
     name: str = "ssm"
     blk_c: int = 128
 
     def clamped(self, key: SsmKey) -> "SsmScanConfig":
-        return dataclasses.replace(self, blk_c=_div_clamp(self.blk_c, key.c))
+        return dataclasses.replace(self, blk_c=min(self.blk_c, key.c))
+
+    def mosaic_tileable(self, key: SsmKey) -> bool:
+        """Mosaic tiles the (T, blk_c) slabs iff blk_c is a multiple of
+        128 lanes or the whole channel dim (a ragged last block is fine:
+        see ssm_scan.py)."""
+        return self.blk_c == key.c or self.blk_c % 128 == 0
 
     def vmem_bytes(self, key: SsmKey) -> int:
         """x/dt/y slabs (T, blk_c) + b/c mats (T, N), double-buffered,
@@ -88,9 +86,7 @@ class SsmKernel(api.Kernel):
 
     def config_space(self, key: SsmKey, version: str) -> List[SsmScanConfig]:
         out = []
-        for blk in BLK_C_MENU:
-            if blk > key.c or key.c % blk:
-                continue
+        for blk in sorted({min(b, key.c) for b in BLK_C_MENU}):
             cfg = SsmScanConfig("tune", blk)
             if cfg.vmem_bytes(key) <= TPU_V5E.vmem_bytes:
                 out.append(cfg)
@@ -113,10 +109,12 @@ class SsmKernel(api.Kernel):
     def model_step_s(self, key: SsmKey, config: SsmScanConfig,
                      version: str) -> float:
         cfg = config.clamped(key)
+        n_blk = -(-key.c // cfg.blk_c)
         lane_fill = min(key.n, 128) / 128.0
-        passes = key.b * key.t * key.c * (12.0 * key.n + 2.0)
+        # a ragged last block computes its padding channels too
+        passes = key.b * key.t * n_blk * cfg.blk_c * (12.0 * key.n + 2.0)
         compute_s = passes / PASS_RATE / lane_fill
-        instances = key.b * (key.c // cfg.blk_c)
+        instances = key.b * n_blk
         loop_s = instances * key.t * LOOP_STEP_OVERHEAD_S
         overhead_s = instances * GRID_OVERHEAD_S
         mem_s = scan_lib.kernel_hbm_bytes(key.b, key.t, key.c,
@@ -156,7 +154,7 @@ class SsmKernel(api.Kernel):
 
     def config_divides(self, config: SsmScanConfig, key: SsmKey
                        ) -> List[str]:
-        if config.blk_c <= 0 or key.c % config.blk_c:
+        if config.blk_c <= 0 or not config.mosaic_tileable(key):
             return [f"c={key.c} not tiled by block {config.blk_c}"]
         return []
 

@@ -7,9 +7,14 @@ adapted to TPU: the recurrent state h (C_blk, N) lives in VMEM (registers
 of the recurrence), x/dt stream through once, y streams out once — HBM
 traffic collapses to the kernel's I/O (~0.4s modeled).
 
-Grid: (B, n_c_blocks). Block = the full time axis x (T, C_blk) slab
+Grid: (B, cdiv(C, C_blk)). Block = the full time axis x (T, C_blk) slab
 (T=4096, C_blk=128 -> 2 MiB f32, VMEM-resident), dt same, b/c (T, N).
-The kernel fori-loops T steps, carrying h functionally.
+The kernel fori-loops T steps, carrying h functionally. C_blk is the lane
+dim of the x/dt/y blocks, so Mosaic needs it to be a multiple of 128 or
+the whole of C. A C that 128 does not divide (hymba's 1600) takes a
+ragged last block: channels never mix (every reduction runs over N), so
+the padding lanes of that block compute garbage that is never written
+back.
 
 ref.py oracle = models/mamba.ssm_scan. Validated in interpret mode by
 tests/test_ssm_kernel.py across shape sweeps.
@@ -57,8 +62,7 @@ def ssm_scan_pallas(x, dt, bmat, cmat, a_log, d, h0, *, blk_c: int = 128,
     b, t, c = x.shape
     n = a_log.shape[1]
     blk_c = min(blk_c, c)
-    assert c % blk_c == 0, (c, blk_c)
-    n_c = c // blk_c
+    n_c = pl.cdiv(c, blk_c)
 
     kern = functools.partial(_kernel, t_len=t)
     y, hT = pl.pallas_call(

@@ -93,10 +93,11 @@ def build_params(fn: Callable[[ParamBuilder], Dict],
 
 def matmul(x, w, *, out_dtype=PARAM_DTYPE):
     """bf16 x bf16 -> f32 accumulate -> cast. The MXU-native contraction."""
+    x = isolate_exact_tp(x)
     y = jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    return y.astype(out_dtype)
+    return isolate_exact_tp(y.astype(out_dtype))
 
 
 # --- exact-TP (bit-exact sharded serving) ----------------------------------
@@ -140,22 +141,39 @@ def gather_exact_tp(x):
         x, NamedSharding(_EXACT_TP_MESH, PartitionSpec()))
 
 
+def isolate_exact_tp(x):
+    """Pass x through an optimization barrier when tracing under
+    exact_tp_scope; identity otherwise.
+
+    Bit-exactness across TP degrees also needs every device to compute an
+    op the same way whatever its local width. On TPU, XLA picks fusions
+    and layouts from the per-device shapes, and a projection or an
+    attention core fused with its neighbours can change its accumulation
+    order with the width: codeqwen1.5-7b decode logits differed by up to
+    0.03 between TP4 and TP2 on v5e, although each op alone gives the same
+    bits at both widths. Fencing the activations into and out of the
+    projections and the attention core keeps each out of its neighbours'
+    fusions (the weights stay unfenced, so their slices still stream into
+    the dots), and TP4 and TP2 then match bit for bit."""
+    if _EXACT_TP_MESH is None:
+        return x
+    return jax.lax.optimization_barrier(x)
+
+
 def matmul_rp(x, w):
     """Row-parallel projection (contracting dim sharded over `model`): emit
     bf16 so the cross-shard psum XLA inserts moves HALF the bytes (the
     Megatron bf16-allreduce trick; local MXU accumulation is still f32 —
-    only the cross-chip combine is bf16). Measured in EXPERIMENTS.md §Perf:
-    llama4 prefill collective term 51.5 -> 32.9s, qwen2.5-32b train
-    137 -> 88s.
+    only the cross-chip combine is bf16).
 
     Under exact_tp_scope (sharded serving) the activation is gathered
     first and the weight is replicated by plan, so this contraction is
     computed whole per device — bit-exact, no psum."""
-    x = gather_exact_tp(x)
+    x = gather_exact_tp(isolate_exact_tp(x))
     y = jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.bfloat16)
-    return y
+    return isolate_exact_tp(y)
 
 
 def einsum(subscript, *ops, out_dtype=PARAM_DTYPE):
@@ -229,10 +247,25 @@ def embed(tokens, table):
 
 
 def lm_logits(x, table_or_head):
-    """Project to vocab in f32 (loss numerics)."""
-    return jax.lax.dot_general(
-        x, table_or_head, (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    """Project to vocab in f32 (loss numerics).
+
+    Under exact_tp_scope the rows are flattened, and a single row (the
+    last prefill token) is padded to 8: XLA emits a one-row product
+    differently at different vocab shard widths, an 8-row one the same
+    way."""
+    def proj(a):
+        return jax.lax.dot_general(
+            a, table_or_head, (((a.ndim - 1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if _EXACT_TP_MESH is None:
+        return proj(x)
+    lead = x.shape[:-1]
+    rows = isolate_exact_tp(x).reshape(-1, x.shape[-1])
+    n = rows.shape[0]
+    if n == 1:
+        rows = isolate_exact_tp(jnp.pad(rows, ((0, 7), (0, 0))))
+    return isolate_exact_tp(proj(rows)[:n].reshape(*lead, -1))
 
 
 def softmax_xent(logits_f32, labels, *, z_loss: float = 1e-4):

@@ -31,8 +31,8 @@ from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
 from repro.models import rwkv6 as rwkv_lib
 from repro.models.layers import (
-    PARAM_DTYPE, DistCtx, ParamBuilder, apply_rope, embed, matmul,
-    matmul_rp, swiglu,
+    PARAM_DTYPE, DistCtx, ParamBuilder, apply_rope, embed, isolate_exact_tp,
+    matmul, matmul_rp, swiglu,
 )
 
 PyTree = Any
@@ -228,6 +228,14 @@ def build_param_fn(cfg: ModelConfig) -> Callable[[ParamBuilder], Dict]:
 # blocks (apply)
 # ===========================================================================
 
+def _attend(fn, q, k, v, *args, **kw):
+    """fn(q, k, v, *args) with its operands and result fenced under
+    exact-TP (layers.isolate_exact_tp): the attention core is one of the
+    ops whose bits would otherwise follow the per-device head count."""
+    q, k, v = isolate_exact_tp((q, k, v))
+    return isolate_exact_tp(fn(q, k, v, *args, **kw))
+
+
 def _qkv(lp, x, cfg: ModelConfig):
     b_, s, _ = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -260,15 +268,16 @@ def attn_block(lp, x, cfg: ModelConfig, *, positions, window=0, rope=True,
     if (cfg.use_flash_attention and window == 0 and s % 256 == 0
             and kv_valid is None):
         # Pallas flash kernel: VMEM-blocked online softmax — no (S,S)
-        # score tensor ever reaches HBM (EXPERIMENTS.md §Perf iteration 2).
+        # score tensor ever reaches HBM.
         # On CPU this runs in interpret mode (tests); the dry-run models its
         # traffic analytically (launch/dryrun.py flash adjustment) because
         # the interpret-mode while-loop carries full arrays with per-step
         # copies that misrepresent the kernel's true HBM traffic.
-        out = attn_lib.flash_attention_spmd(q, k, v, ctx, causal=True)
+        out = _attend(attn_lib.flash_attention_spmd, q, k, v, ctx,
+                      causal=True)
     else:
-        out = attn_lib.chunked_causal_attention(q, k, v, window=window,
-                                                kv_valid=kv_valid)
+        out = _attend(attn_lib.chunked_causal_attention, q, k, v,
+                      window=window, kv_valid=kv_valid)
     out = matmul_rp(out.reshape(b_, s, -1), lp["wo"])
     return out, (k, v)
 
@@ -307,13 +316,14 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, pos,
         # ring buffer (sliding window): every slot <= cache_len-1 is valid;
         # window masking is implicit in the buffer size
         eff_len = jnp.minimum(cache_len, lcache)
-        out = attn_lib.decode_attention(q, cache_k, cache_v, eff_len)
+        out = _attend(attn_lib.decode_attention, q, cache_k, cache_v,
+                      eff_len)
     elif ctx is not None and ctx.kv_seq_shard and not per_row:
         out = attn_lib.flash_decode_sharded(q, cache_k, cache_v, cache_len,
                                             ctx=ctx, window=window)
     else:
-        out = attn_lib.decode_attention(q, cache_k, cache_v, cache_len,
-                                        window=window)
+        out = _attend(attn_lib.decode_attention, q, cache_k, cache_v,
+                      cache_len, window=window)
     out = matmul_rp(out.reshape(b_, 1, -1), lp["wo"])
     return out, (cache_k, cache_v)
 

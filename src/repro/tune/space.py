@@ -2,8 +2,9 @@
 
 Generalizes `journey.sweep_blocks`' fixed grid to any `GppSize`: a candidate
 block size must (a) exactly tile every axis it blocks (the kernel asserts
-divisibility), and (b) keep the analytic VMEM working set inside the chip's
-VMEM budget (double-buffered inputs + live intermediates, BlockConfig
+divisibility), (b) be a block Mosaic can tile (multiples of 8 sublanes and
+128 lanes, or the whole dim), and (c) keep the analytic VMEM working set
+inside the chip's VMEM budget (double-buffered inputs + live intermediates, BlockConfig
 .vmem_bytes). The menu is geometric — powers of two per axis — because the
 TPU's 8x128 VREG/DMA granularity makes intermediate sizes strictly worse
 than the nearest power of two on at least one of lane fill or traffic.
@@ -33,7 +34,8 @@ def candidates(size: GppSize, *, fused: bool = True,
                aqsm_transposed: bool = True,
                vmem_budget: int = TPU_V5E.vmem_bytes) -> List[BlockConfig]:
     """All feasible BlockConfigs for `size`: divisibility-exact on every
-    axis and VMEM-feasible. Deterministic order (menu order)."""
+    axis, tileable by Mosaic and VMEM-feasible. Deterministic order (menu
+    order)."""
     out = []
     for big in _divisors(size.ncouls, IG_MENU):
         for bigp in _divisors(size.ngpown, IGP_MENU):
@@ -41,6 +43,7 @@ def candidates(size: GppSize, *, fused: bool = True,
                 cfg = BlockConfig("tune", big, bigp, bb,
                                   aqsm_transposed=aqsm_transposed,
                                   fused_acc=fused)
-                if cfg.vmem_bytes(size.nw) <= vmem_budget:
+                if (cfg.mosaic_tileable(size)
+                        and cfg.vmem_bytes(size.nw) <= vmem_budget):
                     out.append(cfg)
     return out

@@ -12,6 +12,7 @@ import pytest
 from _prop import given, settings, st
 
 from repro.dist.sharding import ShardingPlan, spec_for
+from repro.launch.mesh import make_mesh
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -287,10 +288,9 @@ def test_bubble_fraction_model():
 def test_batch_and_params_sharding_trees():
     """Tree builders produce NamedSharding leaves (real 1-device mesh; the
     axis-assignment rules themselves are covered by the fakes above)."""
-    import jax
     from repro.dist.sharding import (ShardingPlan, batch_shardings,
                                      params_shardings)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = make_mesh((1, 1, 1), ("pod", "data", "model"))
     p = ShardingPlan(mesh=mesh, dp_axes=("pod", "data"))
     bs = batch_shardings(p, {"tokens": types.SimpleNamespace(shape=(256, 4096)),
                              "labels": types.SimpleNamespace(shape=(256, 4096)),
@@ -325,10 +325,11 @@ def _run_sub(code: str):
 def test_flash_decode_matches_plain():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.models.attention import flash_decode_sharded, decode_attention
         from repro.models.layers import DistCtx
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         ctx = DistCtx(mesh=mesh)
         B,H,KvH,Hd,L = 4, 8, 2, 64, 256
         ks = jax.random.split(jax.random.PRNGKey(0), 3)
@@ -351,9 +352,10 @@ def test_flash_decode_matches_plain():
 def test_int8_allreduce_close_to_exact():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.optim.compress import allreduce_int8, init_residual
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         g = {"w": jax.random.normal(jax.random.PRNGKey(0), (8, 64))}
         res = init_residual(g)
         with jax.set_mesh(mesh):
@@ -375,10 +377,11 @@ def test_sharded_train_step_runs():
     (params+opt donated), loss finite and changing."""
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_config, reduce_config
         from repro.models.registry import build_model
         from repro.train import step as step_lib
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         cfg = reduce_config(get_config("phi4-mini-3.8b"))
         model = build_model(cfg)
         plan = step_lib.make_plan(cfg, mesh, kind="train")
@@ -409,8 +412,9 @@ def test_sharded_train_step_runs():
 def test_pipeline_parallel_matches_dense():
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.dist.pipeline import pipelined_apply, bubble_fraction
-        mesh = jax.make_mesh((4,), ("model",))
+        mesh = make_mesh((4,), ("model",))
         L, M, B, D = 8, 4, 2, 16
         ks = jax.random.split(jax.random.PRNGKey(0), 2)
         w = jax.random.normal(ks[0], (L, D, D)) * 0.1
@@ -439,19 +443,19 @@ def test_elastic_checkpoint_reshard():
     (elastic scaling: 8 -> 4 devices)."""
     _run_sub("""
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
+        from repro.launch.mesh import make_mesh
         from jax.sharding import PartitionSpec as P, NamedSharding
         from repro.ckpt.checkpoint import CheckpointManager
         d = tempfile.mkdtemp()
         mgr = CheckpointManager(d)
-        mesh8 = jax.make_mesh((8,), ("model",))
+        mesh8 = make_mesh((8,), ("model",))
         w = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
         with jax.set_mesh(mesh8):
             wd = jax.device_put(w, NamedSharding(mesh8, P("model", None)))
         mgr.save(1, {"w": wd}, blocking=True)
         # "lose half the fleet": restore onto a 4-device mesh
         devs = jax.devices()[:4]
-        from jax.sharding import Mesh
-        mesh4 = Mesh(np.array(devs), ("model",))
+        mesh4 = make_mesh((4,), ("model",), devices=devs)
         sh = {"w": NamedSharding(mesh4, P("model", None))}
         step, tree = mgr.restore(shardings=sh)
         assert step == 1

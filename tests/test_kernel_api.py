@@ -189,6 +189,23 @@ def test_on_tpu_false_on_cpu():
     assert backend.backend_name() == "cpu"
 
 
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """$JAX_COMPILATION_CACHE_DIR wins and is left for JAX to read; without
+    it the cache goes to the fixed <repo>/.jax_cache."""
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(backend.CACHE_ENV, str(tmp_path))
+        assert backend.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was    # nothing set
+        monkeypatch.delenv(backend.CACHE_ENV)
+        d = backend.enable_compile_cache()
+        assert os.path.samefile(os.path.dirname(d), ROOT)
+        assert os.path.basename(d) == ".jax_cache"
+        assert jax.config.jax_compilation_cache_dir == d
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 # ---------------------------------------------------------------------------
 # deprecation shims: bit-identical + a single warning
 # ---------------------------------------------------------------------------
@@ -247,8 +264,8 @@ def test_dispatch_odd_shapes_fall_back_to_clamped_static():
         assert not np.any(np.isnan(np.asarray(out))), s
         np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
                                    atol=1e-4, rtol=1e-4)
-    # c=6: clamp (min) tiles; c=130: 128 doesn't divide it — the divisor
-    # clamp must pick a tiling block instead of tripping the kernel assert
+    # c=6: the clamp (min) takes the whole dim; c=130: a 128 block leaves
+    # a ragged last block, whose padding channels must not leak into y
     for c in (6, 130):
         args = _ssm_inputs(SsmKey(b=2, t=16, c=c, n=4))
         y, hT = api.dispatch("ssm", *args)

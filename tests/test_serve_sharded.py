@@ -22,6 +22,7 @@ import pytest
 
 from repro.configs.base import get_config, reduce_config
 from repro.dist.sharding import ShardingPlan, serve_specs, spec_for
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.serve.engine import Request, ServeEngine
 
@@ -64,7 +65,7 @@ def test_serve_specs_structure_and_replication():
     cache leaf, (B,) pos + logits replicated (host-side scheduler)."""
     cfg = reduce_config(get_config("qwen2-1.5b"), layers=2, d_model=64,
                         vocab=128)
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     specs = serve_specs(cfg, mesh, max_batch=2, cache_len=32)
     assert specs.plan.tp_out_dims_only and specs.plan.dp_axes == ()
     model = build_model(cfg)
@@ -91,7 +92,7 @@ def test_one_device_mesh_bitexact_and_device_stats():
                 for i in range(3)]
 
     ref = ServeEngine(cfg, params, max_batch=2, cache_len=64).run(mk())
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     eng = ServeEngine(cfg, params, max_batch=2, cache_len=64, mesh=mesh)
     out, stats = eng.run(mk(), collect_stats=True)
     assert out == ref
@@ -101,6 +102,32 @@ def test_one_device_mesh_bitexact_and_device_stats():
     assert d["params_bytes"] > 0 and d["cache_bytes"] > 0
     assert d["occupancy"] == e["occupancy"]
     assert eng.device_stats()[0]["params_bytes"] == d["params_bytes"]
+
+
+@pytest.mark.parametrize("rows", [1, 4])
+def test_exact_tp_fences_only_inside_scope(rows):
+    """Under exact_tp_scope the projections fence their activations with
+    optimization barriers (and a one-row lm_head pads to 8 rows); outside
+    it the trace holds no barrier. The values agree either way."""
+    import jax.numpy as jnp
+    from repro.models.layers import exact_tp_scope, lm_logits, matmul
+    x = jax.random.normal(jax.random.PRNGKey(0), (rows, 1, 64), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(1), (64, 96), jnp.bfloat16)
+
+    def mk():       # a fresh function per trace: the scope is trace-time
+        return lambda x, w: lm_logits(matmul(x, w[:, :64]), w)
+
+    f = mk()
+    plain = str(jax.make_jaxpr(f)(x, w))
+    with exact_tp_scope(make_mesh((1,), ("model",))):
+        fenced = str(jax.make_jaxpr(mk())(x, w))
+        got = jax.jit(mk())(x, w)
+    assert "optimization_barrier" not in plain
+    assert "optimization_barrier" in fenced
+    assert ("pad" in fenced) == (rows == 1)
+    assert got.shape == (rows, 1, 96)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(f(x, w)),
+                               rtol=1e-5, atol=1e-5)
 
 
 # ------------------------------------------------- multi-device (subprocess)
@@ -126,6 +153,7 @@ def test_sharded_engine_bitexact_8dev_dense_and_moe():
     than the global leaf), greedy and temperature sampling mixed."""
     _run_sub("""
         import jax, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.configs.base import get_config, reduce_config
         from repro.models.registry import build_model
         from repro.serve.engine import Request, ServeEngine
@@ -142,7 +170,7 @@ def test_sharded_engine_bitexact_8dev_dense_and_moe():
                                 temperature=(0.7 if i == 1 else 0.0))
                         for i in range(5)]
             ref = ServeEngine(cfg, params, max_batch=2, cache_len=64).run(mk())
-            mesh = jax.make_mesh((8,), ("model",))
+            mesh = make_mesh((8,), ("model",))
             eng = ServeEngine(cfg, params, max_batch=2, cache_len=64,
                               mesh=mesh)
             out = eng.run(mk())

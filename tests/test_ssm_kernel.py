@@ -28,6 +28,7 @@ def _mk(b, t, c, n, seed=0):
     (2, 64, 8, 4, 4),
     (1, 128, 16, 8, 8),
     (3, 32, 8, 16, 8),
+    (1, 16, 200, 4, 128),      # ragged last channel block (hymba's 1600)
 ])
 def test_shape_sweep(b, t, c, n, blk):
     args = _mk(b, t, c, n)

@@ -7,6 +7,7 @@ import pytest
 
 from repro.configs.base import get_config, reduce_config
 from repro.core.journey import OP_MIX, run_journey, sweep_blocks
+from repro.launch.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.serve.engine import Request, ServeEngine
 from repro.train.trainer import TrainLoopConfig, Trainer
@@ -18,7 +19,7 @@ def _tiny_loop(tmp_path, total_steps, ckpt_every=4):
     loop = TrainLoopConfig(total_steps=total_steps, ckpt_every=ckpt_every,
                            log_every=100, ckpt_dir=str(tmp_path / "ckpt"),
                            seq_len=32, global_batch=4, peak_lr=1e-3)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return cfg, Trainer(cfg, loop, mesh)
 
 

@@ -260,29 +260,46 @@ def dispatch(name: str, *args, version: Optional[str] = None,
     sharded ServeEngine keys the ssm scan on channels/tp so cached block
     configs match what each device actually executes).
 
+    Under `jax.profiler` the call shows as the host span
+    `kernel.dispatch` (args `name`, `version`), holding `kernel.config`
+    while the config resolves, and the kernel's device ops carry the
+    named scope `kernel.<name>`.
+
     Example::
 
         import repro
         from repro.kernels.gpp import problem
         ach, asx = repro.dispatch("gpp", problem.make_inputs(problem.TINY))
     """
+    import jax
     k = get_kernel(name)
     version = version or k.default_version
     if version not in k.versions:
         raise ValueError(f"unknown {k.name} version {version!r}; "
                          f"have {list(k.versions)}")
-    if config is None:
-        key = problem_key if problem_key is not None \
-            else k.problem_key(*args, **kwargs)
-        if version in k.tunable and k.config_space(key, version):
-            from repro.tune import tuner    # deferred: tune is optional here
-            config = tuner.tune_kernel(k.name, key, version=version).config
-        else:
-            # static versions, and tunable ones at shapes the candidate
-            # menu can't tile (empty space): the clamped static config —
-            # the legacy entry points' behavior for odd sizes
-            config = k.static_config(key, version)
-            if config is None and version in k.tunable:
-                raise ValueError(f"no feasible {k.name} config for {key}")
-    return k.run(*args, version=version, config=config, interpret=interpret,
-                 **kwargs)
+    with jax.profiler.TraceAnnotation("kernel.dispatch", name=k.name,
+                                      version=version):
+        if config is None:
+            with jax.profiler.TraceAnnotation("kernel.config"):
+                config = _resolve_config(k, version, problem_key, args,
+                                         kwargs)
+        with jax.named_scope(f"kernel.{k.name}"):
+            return k.run(*args, version=version, config=config,
+                         interpret=interpret, **kwargs)
+
+
+def _resolve_config(k: Kernel, version: str, problem_key: Any,
+                    args: tuple, kwargs: dict) -> Any:
+    """The config `dispatch` runs when the caller gives none."""
+    key = problem_key if problem_key is not None \
+        else k.problem_key(*args, **kwargs)
+    if version in k.tunable and k.config_space(key, version):
+        from repro.tune import tuner    # deferred: tune is optional here
+        return tuner.tune_kernel(k.name, key, version=version).config
+    # static versions, and tunable ones at shapes the candidate menu
+    # can't tile (empty space): the clamped static config — the legacy
+    # entry points' behavior for odd sizes
+    config = k.static_config(key, version)
+    if config is None and version in k.tunable:
+        raise ValueError(f"no feasible {k.name} config for {key}")
+    return config

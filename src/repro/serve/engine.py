@@ -314,21 +314,25 @@ class ServeEngine:
 
         from repro.models.layers import exact_tp_scope
 
+        # the jitted functions' names are the compiled modules' names
+        # (jit__decode_masked, jit__prefill_slot), which a profile reads;
+        # the named scopes tag every device op they hold
         def _decode_masked(p, c, t, active):
             # exact_tp_scope is trace-time: with a mesh it makes every
             # row-parallel contraction gather-then-compute (bit-exact);
             # mesh=None makes it a no-op.
-            with exact_tp_scope(mesh):
-                logits, new = self.model.decode_step(p, c, t, self._ctx)
-            # done-row masking: hold finished slots' pos so their cache
-            # rows stop growing — the step writes one (masked, invisible)
-            # line at the held position and the row costs nothing
-            # semantically.
-            new["pos"] = jnp.where(active, new["pos"], c["pos"])
+            with jax.named_scope("serve.decode"):
+                with exact_tp_scope(mesh):
+                    logits, new = self.model.decode_step(p, c, t, self._ctx)
+                # done-row masking: hold finished slots' pos so their
+                # cache rows stop growing — the step writes one (masked,
+                # invisible) line at the held position and the row costs
+                # nothing semantically.
+                new["pos"] = jnp.where(active, new["pos"], c["pos"])
             return logits, new
 
         def _prefill_slot(p, c, s, b, n):
-            with exact_tp_scope(mesh):
+            with jax.named_scope("serve.prefill"), exact_tp_scope(mesh):
                 return self.model.prefill_into_slot(p, c, s, b, n,
                                                     self._ctx)
 
@@ -357,7 +361,7 @@ class ServeEngine:
         self._prefill_cont = None
         if kv_page_size and self.model.prefill_continue is not None:
             def _prefill_cont(p, c, s, b, st, n):
-                with exact_tp_scope(mesh):
+                with jax.named_scope("serve.prefill"), exact_tp_scope(mesh):
                     return self.model.prefill_continue(p, c, s, b, st, n,
                                                        self._ctx)
             self._prefill_cont = jax.jit(_prefill_cont)
@@ -399,15 +403,15 @@ class ServeEngine:
         Greedy rows take argmax; temperature rows draw categorically with a
         per-request key fold_in(fold_in(base, rid), token_index) — no row's
         draw depends on its batch-mates or on any mutable RNG state."""
-        lg = logits.astype(jnp.float32).reshape(logits.shape[0], -1)
-        greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-
         def draw(rid, ngen, row, temp):
             key = jax.random.fold_in(jax.random.fold_in(base_key, rid), ngen)
             return jax.random.categorical(key, row / jnp.maximum(temp, 1e-6))
 
-        sampled = jax.vmap(draw)(rids, ngens, lg, temps).astype(jnp.int32)
-        return jnp.where(temps > 0, sampled, greedy)
+        with jax.named_scope("serve.sample"):
+            lg = logits.astype(jnp.float32).reshape(logits.shape[0], -1)
+            greedy = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+            sampled = jax.vmap(draw)(rids, ngens, lg, temps).astype(jnp.int32)
+            return jnp.where(temps > 0, sampled, greedy)
 
     @staticmethod
     def _spec_sample_impl(logits: jax.Array, temps: jax.Array,
@@ -431,12 +435,17 @@ class ServeEngine:
         return jnp.where(temps > 0, sampled, greedy)
 
     def _sample_rows(self, logits, slots: List[Optional[_Slot]]) -> np.ndarray:
-        temps = np.array([s.temperature if s else 0.0 for s in slots],
-                         np.float32)
-        rids = np.array([s.rid if s else -1 for s in slots], np.int32)
-        ngens = np.array([s.n_gen if s else 0 for s in slots], np.int32)
-        return np.asarray(self._sample(logits, jnp.asarray(temps), self.rng,
-                                       jnp.asarray(rids), jnp.asarray(ngens)))
+        """Sample every row and pull the tokens to the host, a
+        device-to-host round trip (span `serve.sample`)."""
+        with jax.profiler.TraceAnnotation(
+                "serve.sample", rows=sum(s is not None for s in slots)):
+            temps = np.array([s.temperature if s else 0.0 for s in slots],
+                             np.float32)
+            rids = np.array([s.rid if s else -1 for s in slots], np.int32)
+            ngens = np.array([s.n_gen if s else 0 for s in slots], np.int32)
+            return np.asarray(self._sample(
+                logits, jnp.asarray(temps), self.rng, jnp.asarray(rids),
+                jnp.asarray(ngens)))
 
     # ------------------------------------------------------------ admission
 
@@ -500,70 +509,88 @@ class ServeEngine:
 
     def _admit(self, cache, slot_idx: int, r: Request, t_enqueue: float):
         """Prefill r into slot_idx's cache lines; returns
-        (new cache, slot state, first sampled token)."""
-        plen = len(r.prompt)
-        if self.cfg.family == "vlm":
-            plen += self.cfg.n_vis_tokens  # vis tokens occupy cache lines
-        # spec rounds write up to spec_k speculative lines past the
-        # committed region before the accept decision rolls pos back, so a
-        # spec engine reserves that headroom in every slot
-        assert plen + r.max_new_tokens + self.spec_k <= self.cache_len, (
-            f"request {r.rid}: prompt {plen} + max_new {r.max_new_tokens} "
-            f"+ spec_k {self.spec_k} exceeds cache_len {self.cache_len}")
-        vis = plen - len(r.prompt)
-        # paged cache: open the block table (allocating pages for the
-        # prompt) and consult the prefix index. A hit restores the cached
-        # K/V pages into the slot row and prefills only the unseen suffix.
-        hit = None
-        if self._kv is not None:
-            hit = self._kv.admit(r.rid, np.asarray(r.prompt, np.int32),
-                                 plen, r.max_new_tokens)
-        t_admit = time.perf_counter()
-        if hit is not None:
-            cache = self._kv.restore_prefix(cache, slot_idx, hit)
-            start = hit.tokens
-            suffix = np.asarray(r.prompt[start:], np.int32)
-            padded = self._bucket_len(len(suffix), self.cache_len - start)
-            toks = np.zeros((1, padded), np.int32)
-            toks[0, : len(suffix)] = suffix
-            logits, cache = self._prefill_cont(
-                self.params, cache, np.int32(slot_idx),
-                {"tokens": jnp.asarray(toks)}, np.int32(start),
-                np.int32(len(suffix)))
-        else:
-            padded = self._bucket_len(len(r.prompt), self.cache_len - vis)
-            toks = np.zeros((1, padded), np.int32)
-            toks[0, : len(r.prompt)] = r.prompt  # right pad: masked by pos
-            batch = {"tokens": jnp.asarray(toks)}
-            if r.extra:
-                batch.update(r.extra)
-            logits, cache = self._prefill_slot(
-                self.params, cache, np.int32(slot_idx), batch,
-                np.int32(plen))
-        if self._kv is not None and self._kv.prefix_reuse:
-            # publish this prompt's full pages for future admissions
-            cache = self._kv.insert_prefix(np.asarray(r.prompt, np.int32),
-                                           r.rid, cache, slot_idx)
-        if self.spec_k:
-            # the draft keeps its own slot-resident K/V lines (never
-            # page-accounted: the page pool tracks committed TARGET lines
-            # only) and always prefills the full prompt — it has no prefix
-            # store, and its first-token logits are discarded (the first
-            # token comes from the target, the bit-exactness contract)
-            dpad = self._bucket_len(len(r.prompt), self.cache_len)
-            dtoks = np.zeros((1, dpad), np.int32)
-            dtoks[0, : len(r.prompt)] = r.prompt
-            _, self._draft_cache = self._draft_prefill(
-                self.draft_params, self._draft_cache, np.int32(slot_idx),
-                {"tokens": jnp.asarray(dtoks)}, np.int32(plen))
-        slot = _Slot(rid=r.rid, temperature=r.temperature,
-                     remaining=r.max_new_tokens, n_gen=0, prompt_len=plen,
-                     t_enqueue=t_enqueue, t_admit=t_admit, t_first=0.0)
-        first = int(self._sample_rows(logits, [slot])[0])
-        slot.t_first = time.perf_counter()
-        slot.n_gen = 1
-        slot.remaining -= 1
-        return cache, slot, first
+        (new cache, slot state, first sampled token). The span
+        `serve.admit` covers the admission up to the first token on the
+        host; its args tie it to the request and its prefill bucket."""
+        with jax.profiler.TraceAnnotation("serve.admit", rid=r.rid,
+                                          prompt_len=len(r.prompt)) as span:
+            plen = len(r.prompt)
+            if self.cfg.family == "vlm":
+                plen += self.cfg.n_vis_tokens  # vis tokens take cache lines
+            # spec rounds write up to spec_k speculative lines past the
+            # committed region before the accept decision rolls pos back,
+            # so a spec engine reserves that headroom in every slot
+            assert plen + r.max_new_tokens + self.spec_k <= self.cache_len, (
+                f"request {r.rid}: prompt {plen} + max_new "
+                f"{r.max_new_tokens} + spec_k {self.spec_k} exceeds "
+                f"cache_len {self.cache_len}")
+            vis = plen - len(r.prompt)
+            # paged cache: open the block table (allocating pages for the
+            # prompt) and consult the prefix index. A hit restores the
+            # cached K/V pages into the slot row and prefills only the
+            # unseen suffix.
+            hit = None
+            if self._kv is not None:
+                hit = self._kv.admit(r.rid, np.asarray(r.prompt, np.int32),
+                                     plen, r.max_new_tokens)
+            t_admit = time.perf_counter()
+            if hit is not None:
+                cache = self._kv.restore_prefix(cache, slot_idx, hit)
+                start = hit.tokens
+                suffix = np.asarray(r.prompt[start:], np.int32)
+                real = len(suffix)
+                padded = self._bucket_len(real, self.cache_len - start)
+                toks = np.zeros((1, padded), np.int32)
+                toks[0, :real] = suffix
+                with jax.profiler.TraceAnnotation("serve.prefill"):
+                    logits, cache = self._prefill_cont(
+                        self.params, cache, np.int32(slot_idx),
+                        {"tokens": jnp.asarray(toks)}, np.int32(start),
+                        np.int32(real))
+            else:
+                real = len(r.prompt)
+                padded = self._bucket_len(real, self.cache_len - vis)
+                toks = np.zeros((1, padded), np.int32)
+                toks[0, :real] = r.prompt  # right pad: masked by pos
+                batch = {"tokens": jnp.asarray(toks)}
+                if r.extra:
+                    batch.update(r.extra)
+                with jax.profiler.TraceAnnotation("serve.prefill"):
+                    logits, cache = self._prefill_slot(
+                        self.params, cache, np.int32(slot_idx), batch,
+                        np.int32(plen))
+            span.set_metadata(bucket=padded)
+            # prompt tokens prefilled, and the padded bucket's tokens
+            # that prefill paid for (finalize() reports both)
+            self._n_prefill_tokens += real
+            self._n_prefill_bucket_tokens += padded
+            if self._kv is not None and self._kv.prefix_reuse:
+                # publish this prompt's full pages for future admissions
+                cache = self._kv.insert_prefix(
+                    np.asarray(r.prompt, np.int32), r.rid, cache, slot_idx)
+            if self.spec_k:
+                # the draft keeps its own slot-resident K/V lines (never
+                # page-accounted: the page pool tracks committed TARGET
+                # lines only) and always prefills the full prompt — it has
+                # no prefix store, and its first-token logits are discarded
+                # (the first token comes from the target, the bit-exactness
+                # contract)
+                dpad = self._bucket_len(len(r.prompt), self.cache_len)
+                dtoks = np.zeros((1, dpad), np.int32)
+                dtoks[0, : len(r.prompt)] = r.prompt
+                _, self._draft_cache = self._draft_prefill(
+                    self.draft_params, self._draft_cache,
+                    np.int32(slot_idx), {"tokens": jnp.asarray(dtoks)},
+                    np.int32(plen))
+            slot = _Slot(rid=r.rid, temperature=r.temperature,
+                         remaining=r.max_new_tokens, n_gen=0,
+                         prompt_len=plen, t_enqueue=t_enqueue,
+                         t_admit=t_admit, t_first=0.0)
+            first = int(self._sample_rows(logits, [slot])[0])
+            slot.t_first = time.perf_counter()
+            slot.n_gen = 1
+            slot.remaining -= 1
+            return cache, slot, first
 
     # ------------------------------------------------------------ scheduler
     #
@@ -604,6 +631,8 @@ class ServeEngine:
         self._cur = np.zeros((self.max_batch, 1), np.int32)
         self._n_steps = 0          # global batched decode steps
         self._n_prefills = 0
+        self._n_prefill_tokens = 0
+        self._n_prefill_bucket_tokens = 0
         self._n_submitted = 0
         self._slot_steps_active = 0
         self._t_start = time.perf_counter()
@@ -675,7 +704,14 @@ class ServeEngine:
         (each free slot index gets at most one admission attempt per
         round), then run one batched decode step over the occupied slots.
         Returns a StepReport; with nothing occupied after admission the
-        decode is skipped (decoded=0)."""
+        decode is skipped (decoded=0). The span `serve.round` covers the
+        round, with the round's admissions and decoded rows as args."""
+        with jax.profiler.TraceAnnotation("serve.round") as span:
+            rep = self._round()
+            span.set_metadata(admitted=len(rep.admitted), rows=rep.decoded)
+        return rep
+
+    def _round(self) -> StepReport:
         admitted: List[int] = []
         finished: List[int] = []
         for i in range(self.max_batch):
@@ -704,9 +740,10 @@ class ServeEngine:
         if self.spec_k:
             return self._spec_round(admitted, finished)
         active = np.array([s is not None for s in self._slots])
-        logits, self._cache = self._decode(self.params, self._cache,
-                                           jnp.asarray(self._cur),
-                                           jnp.asarray(active))
+        with jax.profiler.TraceAnnotation("serve.decode"):
+            logits, self._cache = self._decode(self.params, self._cache,
+                                               jnp.asarray(self._cur),
+                                               jnp.asarray(active))
         self._n_steps += 1
         self._slot_steps_active += int(active.sum())
         toks = self._sample_rows(logits, self._slots)
@@ -908,9 +945,13 @@ class ServeEngine:
                 {**d, "occupancy": engine_stats["occupancy"],
                  "tok_per_s": engine_stats["tok_per_s"]}
                 for d in per_dev]
+        # merged here (not in aggregate_engine_stats, whose schema is
+        # pinned by tests/test_serve_stats.py): prompt tokens prefilled,
+        # and the tokens of the padded buckets that prefill computed
+        engine_stats["prefill_tokens"] = self._n_prefill_tokens
+        engine_stats["prefill_bucket_tokens"] = self._n_prefill_bucket_tokens
         if self._kv is not None:
-            # merged here (not in aggregate_engine_stats, whose schema is
-            # pinned by tests/test_serve_stats.py)
+            # merged outside the pinned schema, like the counters above
             engine_stats["kvcache"] = self._kv.stats()
         if self.spec_k and self._spec is not None:
             # same pattern as kvcache: merged outside the pinned schema

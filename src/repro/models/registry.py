@@ -136,25 +136,36 @@ def _dense_prefill_stack(cfg: ModelConfig, layers, x, positions, *,
 
 def _dense_decode_stack(cfg: ModelConfig, layers, x, cache, *, ctx,
                         window: int = 0, ring: bool = False):
+    """One decode token through dense/moe layers. The stacked K/V caches
+    ride the layer loop's carry, so each layer writes only its new lines
+    (T.attn_block_decode); as scan inputs and outputs they would be
+    rebuilt whole every step."""
     pos = cache["pos"]
 
     def body(carry, inp):
-        x = carry
-        lp, ck, cv = inp
+        x, ks, vs = carry
+        lp, i = inp
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, (ck, cv) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ck,
-                                          cache_v=cv, pos=pos, window=window,
-                                          ctx=ctx, ring=ring)
+        a, (ks, vs) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ks,
+                                          cache_v=vs, layer=i, pos=pos,
+                                          window=window, ctx=ctx, ring=ring)
         x = x + a
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         if "moe" in lp:
             f, _ = _moe_apply(cfg, lp["moe"], h, ctx)
         else:
             f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-        return x + f, (ck, cv)
+        return (x + f, ks, vs), None
 
-    x, (ks, vs) = jax.lax.scan(body, x, (layers, cache["k"], cache["v"]))
-    return x, {"k": ks, "v": vs, "pos": pos + 1, **{k: v for k, v in cache.items() if k not in ("k", "v", "pos")}}
+    (x, ks, vs), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]),
+                                  (layers, _layer_ids(cache["k"])))
+    return x, {**cache, "k": ks, "v": vs, "pos": pos + 1}
+
+
+def _layer_ids(stacked):
+    """Index of each layer of a stacked (n_layers, ...) cache: the scan
+    input beside the layer params when the cache rides the carry."""
+    return jnp.arange(stacked.shape[0], dtype=jnp.int32)
 
 
 # --- rwkv ------------------------------------------------------------------
@@ -230,22 +241,23 @@ def _hymba_stack(cfg: ModelConfig, layers, x, positions, *, remat,
 
     # decode
     def body(carry, inp):
-        x = carry
-        lp, ck, cv, conv_st, h_st = inp
+        x, ks, vs = carry
+        lp, i, conv_st, h_st = inp
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, (ck, cv) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ck,
-                                          cache_v=cv, pos=cache["pos"],
-                                          ring=True)
+        a, (ks, vs) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ks,
+                                          cache_v=vs, layer=i,
+                                          pos=cache["pos"], ring=True)
         m, conv_st, h_st = T.mamba_path(lp["mamba"], h, cfg,
                                         conv_state=conv_st, h_state=h_st,
                                         decode=True, ctx=ctx)
         x = x + fuse(lp, a, m)
         hh = rms_norm(x, lp["ln2"], cfg.norm_eps)
         f = swiglu(hh, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-        return x + f, (ck, cv, conv_st, h_st)
+        return (x + f, ks, vs), (conv_st, h_st)
 
-    x, (ks, vs, conv, hs) = jax.lax.scan(
-        body, x, (layers, cache["k"], cache["v"], cache["conv"], cache["h"]))
+    (x, ks, vs), (conv, hs) = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (layers, _layer_ids(cache["k"]), cache["conv"], cache["h"]))
     return x, {"k": ks, "v": vs, "conv": conv, "h": hs,
                "pos": cache["pos"] + 1}
 
@@ -315,22 +327,24 @@ def _whisper_dec_stack(cfg: ModelConfig, layers, x, enc_out, positions, *,
         return x, ys
 
     def body(carry, inp):
-        x = carry
-        lp, ck, cv, xk, xv = inp
+        x, ks, vs = carry
+        lp, i, xk, xv = inp
         h = layer_norm(x, lp["ln1"], lp["ln1b"], cfg.norm_eps)
-        a, (ck, cv) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ck,
-                                          cache_v=cv, pos=cache["pos"],
-                                          rope=False, ctx=ctx)
+        a, (ks, vs) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ks,
+                                          cache_v=vs, layer=i,
+                                          pos=cache["pos"], rope=False,
+                                          ctx=ctx)
         x = x + a
         h = layer_norm(x, lp["lnx"], lp["lnxb"], cfg.norm_eps)
         x = x + xattn_cached(lp["xattn"], h, xk, xv)
         h = layer_norm(x, lp["ln2"], lp["ln2b"], cfg.norm_eps)
         f = gelu_ffn(h, lp["ffn"]["wi"], lp["ffn"]["bi"], lp["ffn"]["wo"],
                      lp["ffn"]["bo"])
-        return x + f, (ck, cv)
+        return (x + f, ks, vs), None
 
-    x, (ks, vs) = jax.lax.scan(
-        body, x, (layers, cache["k"], cache["v"], cache["xk"], cache["xv"]))
+    (x, ks, vs), _ = jax.lax.scan(
+        body, (x, cache["k"], cache["v"]),
+        (layers, _layer_ids(cache["k"]), cache["xk"], cache["xv"]))
     return x, {"k": ks, "v": vs, "xk": cache["xk"], "xv": cache["xv"],
                "pos": cache["pos"] + 1}
 
@@ -705,19 +719,20 @@ def build_model(cfg: ModelConfig) -> Model:
         pos = cache["pos"]
 
         def body(carry, inp):
-            x = carry
-            lp, ck, cv = inp
+            x, ks, vs = carry
+            lp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, (ck, cv) = T.attn_block_decode_k(lp["attn"], h, cfg,
-                                                cache_k=ck, cache_v=cv,
-                                                pos=pos)
+            a, (ks, vs) = T.attn_block_decode_k(lp["attn"], h, cfg,
+                                                cache_k=ks, cache_v=vs,
+                                                layer=i, pos=pos)
             x = x + a
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
             f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-            return x + f, (ck, cv)
+            return (x + f, ks, vs), None
 
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        (x, ks, vs), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], _layer_ids(cache["k"])))
         new_cache = {"k": ks, "v": vs, "pos": pos + tokens.shape[1]}
         return _logits(params, x), new_cache
 
@@ -744,7 +759,8 @@ def build_model(cfg: ModelConfig) -> Model:
 
         Returns (logits (1,1,V), new cache). Every cache leaf has layout
         (layers, batch, ...), so the write is one dynamic_update_slice at
-        (0, slot, 0, ...) per leaf.
+        (0, slot, 0, ...) per leaf, in place when the caller donates the
+        cache (the serve engine does).
         """
         logits, row = prefill(params, batch, ctx, last_index=prompt_len - 1)
         new = {}
@@ -784,19 +800,20 @@ def build_model(cfg: ModelConfig) -> Model:
         positions = start + jnp.arange(s)
 
         def body(carry, inp):
-            x = carry
-            lp, ck, cv = inp
+            x, ks, vs = carry
+            lp, i = inp
             h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, (ck, cv) = T.attn_block_continue(
-                lp["attn"], h, cfg, cache_k=ck, cache_v=cv, slot=slot,
-                start=start, positions=positions, ctx=ctx)
+            a, (ks, vs) = T.attn_block_continue(
+                lp["attn"], h, cfg, cache_k=ks, cache_v=vs, layer=i,
+                slot=slot, start=start, positions=positions, ctx=ctx)
             x = x + a
             h = rms_norm(x, lp["ln2"], cfg.norm_eps)
             f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-            return x + f, (ck, cv)
+            return (x + f, ks, vs), None
 
-        x, (ks, vs) = jax.lax.scan(
-            body, x, (params["layers"], cache["k"], cache["v"]))
+        (x, ks, vs), _ = jax.lax.scan(
+            body, (x, cache["k"], cache["v"]),
+            (params["layers"], _layer_ids(cache["k"])))
         new_cache = {"k": ks, "v": vs,
                      "pos": cache["pos"].at[slot].set(
                          jnp.asarray(start + n_real, cache["pos"].dtype))}

@@ -282,16 +282,32 @@ def attn_block(lp, x, cfg: ModelConfig, *, positions, window=0, rope=True,
     return out, (k, v)
 
 
-def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, pos,
-                      window=0, rope=True, ctx: Optional[DistCtx] = None,
-                      ring=False):
-    """One-token attention against a cache. cache_k/v: (B,L,KvH,Hd).
+def write_kv_lines(cache, layer, pos, new):
+    """Write each batch row's new K/V lines into one layer of a stacked
+    cache, touching nothing else. cache: (n_layers,B,L,KvH,Hd); new:
+    (B,Q,KvH,Hd); pos: (B,) line of each row's first new line. Row b's
+    lines land at pos[b]..pos[b]+Q-1 of layer `layer`; a line past the
+    cache end is dropped. A caller that carries the cache through its
+    layer loop, in a program that donates it, updates it in place."""
+    b_, qn = new.shape[:2]
+    rows = jnp.arange(b_)[:, None]
+    lines = pos[:, None] + jnp.arange(qn)[None, :]
+    return cache.at[layer, rows, lines].set(new.astype(cache.dtype),
+                                            mode="drop")
+
+
+def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
+                      pos, window=0, rope=True,
+                      ctx: Optional[DistCtx] = None, ring=False):
+    """One-token attention against layer `layer` of the stacked caches
+    cache_k/v: (n_layers,B,L,KvH,Hd). Returns (out, (cache_k, cache_v))
+    with this token's K/V line written.
 
     pos is the write position — a scalar shared by all rows (the classic
-    lockstep decode) or a (B,) vector when every batch row is at its own
-    offset (the serve engine's slot scheduler, where refilled slots join
-    mid-flight). Per-row writes use a one-hot select instead of
-    dynamic_update_slice so each row lands on its own line."""
+    lockstep decode, one dynamic_update_slice) or a (B,) vector when every
+    batch row is at its own offset (the serve engine's slot scheduler,
+    where refilled slots join mid-flight): each row's line then lands at
+    its own position (write_kv_lines)."""
     b_, s, _ = x.shape
     assert s == 1
     per_row = jnp.ndim(pos) == 1
@@ -302,70 +318,68 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, pos,
         pvec = pos[:, None, None] if per_row else jnp.full((1,), pos, jnp.int32)
         q = apply_rope(q.swapaxes(1, 2), pvec, cfg.rope_theta).swapaxes(1, 2)
         k = apply_rope(k.swapaxes(1, 2), pvec, cfg.rope_theta).swapaxes(1, 2)
-    lcache = cache_k.shape[1]
+    lcache = cache_k.shape[2]
     slot = jnp.mod(pos, lcache) if ring else pos
     if per_row:
-        oh = jnp.arange(lcache)[None, :] == slot[:, None]      # (B, L)
-        cache_k = jnp.where(oh[:, :, None, None], k, cache_k)
-        cache_v = jnp.where(oh[:, :, None, None], v, cache_v)
+        cache_k = write_kv_lines(cache_k, layer, slot, k)
+        cache_v = write_kv_lines(cache_v, layer, slot, v)
     else:
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k, (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v, (0, slot, 0, 0))
+        at = (layer, 0, slot, 0, 0)
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k[None], at)
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v[None], at)
+    ck, cv = cache_k[layer], cache_v[layer]
     cache_len = pos + 1
     if ring:
         # ring buffer (sliding window): every slot <= cache_len-1 is valid;
         # window masking is implicit in the buffer size
         eff_len = jnp.minimum(cache_len, lcache)
-        out = _attend(attn_lib.decode_attention, q, cache_k, cache_v,
-                      eff_len)
+        out = _attend(attn_lib.decode_attention, q, ck, cv, eff_len)
     elif ctx is not None and ctx.kv_seq_shard and not per_row:
-        out = attn_lib.flash_decode_sharded(q, cache_k, cache_v, cache_len,
+        out = attn_lib.flash_decode_sharded(q, ck, cv, cache_len,
                                             ctx=ctx, window=window)
     else:
-        out = _attend(attn_lib.decode_attention, q, cache_k, cache_v,
-                      cache_len, window=window)
+        out = _attend(attn_lib.decode_attention, q, ck, cv, cache_len,
+                      window=window)
     out = matmul_rp(out.reshape(b_, 1, -1), lp["wo"])
     return out, (cache_k, cache_v)
 
 
-def attn_block_decode_k(lp, x, cfg: ModelConfig, *, cache_k, cache_v, pos,
-                        window=0, rope=True):
-    """Q-token verify attention against a cache (speculative decoding —
-    serve/spec.py). x: (B,Q,D) holds the Q=k+1 candidate tokens per row;
-    pos: (B,) per-row write offset of the FIRST candidate (identical to the
-    plain decode write position, so a spec round that accepts zero drafts
-    writes the same line plain decode would have).
+def attn_block_decode_k(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
+                        pos, window=0, rope=True):
+    """Q-token verify attention against layer `layer` of the stacked
+    caches (speculative decoding — serve/spec.py). x: (B,Q,D) holds the
+    Q=k+1 candidate tokens per row; pos: (B,) per-row write offset of the
+    FIRST candidate (identical to the plain decode write position, so a
+    spec round that accepts zero drafts writes the same line plain decode
+    would have).
 
-    All Q K/V lines land at pos..pos+Q-1 via Q one-hot selects (a static
-    python loop — Q is small), then one multi-query causal attention where
-    candidate j sees cache positions <= pos+j. Rejected candidates' lines
-    stay in the buffer beyond the rolled-back position; they are invisible
-    (cache_len masking) and are overwritten in the step that first reaches
-    them (write-at-pos precedes the mask that includes pos)."""
+    All Q K/V lines land at pos..pos+Q-1 (write_kv_lines), then one
+    multi-query causal attention where candidate j sees cache positions
+    <= pos+j. Rejected candidates' lines stay in the buffer beyond the
+    rolled-back position; they are invisible (cache_len masking) and are
+    overwritten in the step that first reaches them (write-at-pos precedes
+    the mask that includes pos)."""
     b_, qn, _ = x.shape
     q, k, v = _qkv(lp, x, cfg)
     pvec = pos[:, None] + jnp.arange(qn)[None, :]          # (B,Q) absolute
     if rope and cfg.rope_theta:
         q = apply_rope(q.swapaxes(1, 2), pvec[:, None], cfg.rope_theta).swapaxes(1, 2)
         k = apply_rope(k.swapaxes(1, 2), pvec[:, None], cfg.rope_theta).swapaxes(1, 2)
-    lcache = cache_k.shape[1]
-    for j in range(qn):
-        oh = jnp.arange(lcache)[None, :] == (pos + j)[:, None]    # (B, L)
-        cache_k = jnp.where(oh[:, :, None, None], k[:, j:j + 1], cache_k)
-        cache_v = jnp.where(oh[:, :, None, None], v[:, j:j + 1], cache_v)
-    out = attn_lib.decode_attention_multi(q, cache_k, cache_v, pos + qn,
-                                          window=window)
+    cache_k = write_kv_lines(cache_k, layer, pos, k)
+    cache_v = write_kv_lines(cache_v, layer, pos, v)
+    out = attn_lib.decode_attention_multi(q, cache_k[layer], cache_v[layer],
+                                          pos + qn, window=window)
     out = matmul_rp(out.reshape(b_, qn, -1), lp["wo"])
     return out, (cache_k, cache_v)
 
 
-def attn_block_continue(lp, x, cfg: ModelConfig, *, cache_k, cache_v, slot,
-                        start, positions, ctx=None):
+def attn_block_continue(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
+                        slot, start, positions, ctx=None):
     """Suffix attention for prefix-continue prefill (paged K/V cache with
     prefix reuse — serve/kvcache.py). x: (1,S,D) suffix hidden states whose
-    first token sits at absolute position `start`; cache_k/v: batched
-    (B,Lcache,KvH,Hd) slot caches whose `slot` row already holds the first
-    `start` K/V lines (restored prefix pages).
+    first token sits at absolute position `start`; cache_k/v: stacked
+    (n_layers,B,Lcache,KvH,Hd) slot caches whose `slot` row already holds
+    the first `start` K/V lines of layer `layer` (restored prefix pages).
 
     The suffix k/v are written into the slot row at `start` and the queries
     attend against the FULL row with q_offset=start: keys at absolute
@@ -381,18 +395,15 @@ def attn_block_continue(lp, x, cfg: ModelConfig, *, cache_k, cache_v, slot,
     if cfg.rope_theta:
         q = apply_rope(q.swapaxes(1, 2), positions, cfg.rope_theta).swapaxes(1, 2)
         k = apply_rope(k.swapaxes(1, 2), positions, cfg.rope_theta).swapaxes(1, 2)
-    row_k = jax.lax.dynamic_index_in_dim(cache_k, slot, axis=0, keepdims=True)
-    row_v = jax.lax.dynamic_index_in_dim(cache_v, slot, axis=0, keepdims=True)
-    row_k = jax.lax.dynamic_update_slice(row_k, k.astype(row_k.dtype),
-                                         (0, start, 0, 0))
-    row_v = jax.lax.dynamic_update_slice(row_v, v.astype(row_v.dtype),
-                                         (0, start, 0, 0))
+    at = (layer, slot, start, 0, 0)
+    cache_k = jax.lax.dynamic_update_slice(
+        cache_k, k[None].astype(cache_k.dtype), at)
+    cache_v = jax.lax.dynamic_update_slice(
+        cache_v, v[None].astype(cache_v.dtype), at)
+    row_k = cache_k[layer, slot][None]
+    row_v = cache_v[layer, slot][None]
     out = attn_lib.chunked_causal_attention(q, row_k, row_v, q_offset=start)
     out = matmul_rp(out.reshape(b_, s, -1), lp["wo"])
-    cache_k = jax.lax.dynamic_update_slice(
-        cache_k, row_k, (slot,) + (0,) * (cache_k.ndim - 1))
-    cache_v = jax.lax.dynamic_update_slice(
-        cache_v, row_v, (slot,) + (0,) * (cache_v.ndim - 1))
     return out, (cache_k, cache_v)
 
 

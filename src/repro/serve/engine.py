@@ -336,9 +336,13 @@ class ServeEngine:
                 return self.model.prefill_into_slot(p, c, s, b, n,
                                                     self._ctx)
 
+        # each program that takes the cache (argument 1) and returns it is
+        # donated the cache: it writes its new lines in place and deletes
+        # the arrays it was handed, and the engine rebinds its cache to
+        # the result.
         if mesh is None:
-            self._decode = jax.jit(_decode_masked)
-            self._prefill_slot = jax.jit(_prefill_slot)
+            self._decode = jax.jit(_decode_masked, donate_argnums=1)
+            self._prefill_slot = jax.jit(_prefill_slot, donate_argnums=1)
         else:
             # pin the cache layout across steps (out_shardings) so XLA
             # cannot silently gather the sharded K/V between prefill and
@@ -348,12 +352,12 @@ class ServeEngine:
                 _decode_masked,
                 in_shardings=(sp.params, sp.cache, sp.replicated,
                               sp.replicated),
-                out_shardings=(sp.replicated, sp.cache))
+                out_shardings=(sp.replicated, sp.cache), donate_argnums=1)
             self._prefill_slot = jax.jit(
                 _prefill_slot,
                 in_shardings=(sp.params, sp.cache, sp.replicated,
                               sp.replicated, sp.replicated),
-                out_shardings=(sp.replicated, sp.cache))
+                out_shardings=(sp.replicated, sp.cache), donate_argnums=1)
         self._sample = jax.jit(self._sample_batch_impl)
 
         # suffix prefill for prefix-reuse admissions (dense family only —
@@ -364,7 +368,7 @@ class ServeEngine:
                 with jax.named_scope("serve.prefill"), exact_tp_scope(mesh):
                     return self.model.prefill_continue(p, c, s, b, st, n,
                                                        self._ctx)
-            self._prefill_cont = jax.jit(_prefill_cont)
+            self._prefill_cont = jax.jit(_prefill_cont, donate_argnums=1)
 
         if spec_k:
             if self.model.decode_verify is None:
@@ -388,9 +392,10 @@ class ServeEngine:
                 new["pos"] = jnp.where(active, new["pos"], c["pos"])
                 return logits, new
 
-            self._draft_decode = jax.jit(_draft_decode)
-            self._draft_prefill = jax.jit(_draft_prefill)
-            self._verify = jax.jit(_verify_masked)
+            self._draft_decode = jax.jit(_draft_decode, donate_argnums=1)
+            self._draft_prefill = jax.jit(_draft_prefill,
+                                          donate_argnums=1)
+            self._verify = jax.jit(_verify_masked, donate_argnums=1)
             self._spec_sample = jax.jit(self._spec_sample_impl)
 
     # ------------------------------------------------------------- sampling
@@ -611,6 +616,9 @@ class ServeEngine:
         self._out: Dict[int, List[int]] = {}
         self._per_req: Dict[int, RequestStats] = {}
         self._slots: List[Optional[_Slot]] = [None] * self.max_batch
+        # the last run's device state goes before the next is allocated:
+        # two caches never live at once
+        self._cache = self._draft_cache = self._kv = None
         self._cache = self._fresh_cache()
         if self.kv_page_size:
             from repro.serve.kvcache import PagedKVCache
@@ -619,7 +627,6 @@ class ServeEngine:
                 cache_len=self.cache_len, page_size=self.kv_page_size,
                 n_pages=self.kv_pages, kv_dtype=self.kv_dtype,
                 prefix_reuse=self.prefix_reuse)
-        self._draft_cache = None
         if self.spec_k:
             dc = self.draft_model.init_cache(self.max_batch, self.cache_len)
             dc["pos"] = jnp.zeros((self.max_batch,), jnp.int32)

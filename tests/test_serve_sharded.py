@@ -188,6 +188,47 @@ def test_sharded_engine_bitexact_8dev_dense_and_moe():
 
 
 @pytest.mark.slow
+def test_sharded_engine_donates_its_cache_8dev():
+    """On the 8-device mesh the sharded steps take the cache to keep, as
+    the mesh-less ones do: each step deletes the cache shards it was
+    handed, both compiled programs alias the cache to their outputs, and
+    the tokens stay those of the mesh-less engine."""
+    _run_sub("""
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
+        from repro.configs.base import get_config, reduce_config
+        from repro.models.registry import build_model
+        from repro.serve.engine import Request, ServeEngine
+        cfg = reduce_config(get_config("qwen2-1.5b"), layers=2, d_model=256,
+                            vocab=128)
+        params = build_model(cfg).init_params(jax.random.PRNGKey(0))
+        def mk():
+            return [Request(rid=i, prompt=np.arange(4 + i) % 128,
+                            max_new_tokens=5) for i in range(3)]
+        ref = ServeEngine(cfg, params, max_batch=2, cache_len=64).run(mk())
+        eng = ServeEngine(cfg, params, max_batch=2, cache_len=64,
+                          mesh=make_mesh((8,), ("model",)))
+        eng.reset()
+        dec = eng._decode.lower(eng.params, eng._cache,
+                                jnp.asarray(eng._cur),
+                                jnp.asarray([True, False])).compile()
+        pre = eng._prefill_slot.lower(
+            eng.params, eng._cache, np.int32(0),
+            {"tokens": jnp.zeros((1, 8), jnp.int32)}, np.int32(3)).compile()
+        assert "input_output_alias" in dec.as_text()
+        assert "input_output_alias" in pre.as_text()
+        for r in mk():
+            eng.submit(r)
+        while not eng.idle:
+            held = jax.tree.leaves(eng._cache)
+            eng.step()
+            assert all(a.is_deleted() for a in held)
+        assert eng.outputs == ref, (eng.outputs, ref)
+        print("donated, bit-exact")
+    """)
+
+
+@pytest.mark.slow
 def test_bench_serve_mesh_emits_per_device_rows(tmp_path):
     """`benchmarks/run.py --serve --mesh tp=8` (no pre-set XLA_FLAGS: the
     harness forces the device count itself) writes one serve_device_<i>

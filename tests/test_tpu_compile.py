@@ -11,7 +11,11 @@ compiled program holds the Pallas kernel (`tpu_custom_call`):
     why the journey marks it as not compiled on the chip;
   * flash attention at qwen2-1.5b prefill shapes, forward and backward;
   * the selective scan at hymba-1.5b widths under every channel block
-    the ssm tuner may pick (1600 channels: a ragged last block).
+    the ssm tuner may pick (1600 channels: a ragged last block);
+  * the serve engine's decode and slot-prefill steps at qwen2-1.5b widths
+    (64 slots of 2048 lines), which alias the cache they are handed and
+    write their new lines in place: no whole-cache select or copy, and in
+    decode no whole-cache dynamic-update-slice.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers import every
@@ -19,6 +23,7 @@ test file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -147,3 +152,49 @@ def test_ssm_scan_compiles_at_hymba_widths(one_chip):
             lambda *a, blk=cfg.blk_c: ssm_scan.ssm_scan_pallas(
                 *a, blk_c=blk, interpret=False), *args)
         assert "tpu_custom_call" in txt, cfg
+
+
+@pytest.mark.parametrize("program", ["_decode", "_prefill_slot"])
+def test_serve_steps_write_the_cache_in_place(one_chip, program):
+    """qwen2-1.5b widths with 2 layers, 64 slots of 2048 lines."""
+    from repro.configs.base import ModelConfig
+    from repro.models.registry import build_model
+    from repro.serve.engine import ServeEngine
+    layers, slots, lines = 2, 64, 2048
+    cfg = ModelConfig(name="qwen2-1.5b-2l", family="dense", n_layers=layers,
+                      d_model=1536, n_heads=12, n_kv_heads=2, d_ff=8960,
+                      vocab_size=151936, head_dim=128, qkv_bias=True,
+                      rope_theta=1e6, norm_eps=1e-6, tie_embeddings=True,
+                      remat="none")
+    model = build_model(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = on_chip(model.abstract_params())
+    cache = model.init_cache(slots, lines, abstract=True)
+    cache["pos"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cache = on_chip(cache)
+    eng = ServeEngine(cfg, params, max_batch=slots, cache_len=lines)
+    if program == "_decode":
+        args = (_sds((slots, 1), jnp.int32, one_chip),
+                _sds((slots,), jnp.bool_, one_chip))
+    else:
+        i32 = _sds((), jnp.int32, one_chip)
+        args = (i32, {"tokens": _sds((1, 512), jnp.int32, one_chip)}, i32)
+    txt = getattr(eng, program).lower(params, cache, *args).compile().as_text()
+    # arguments: the weight leaves, then the cache's k, pos and v
+    k_arg = len(jax.tree.leaves(params))
+    alias = re.search(r"input_output_alias=\{(.*?)\} *\}", txt.splitlines()[0])
+    assert alias, "the program aliases none of its arguments"
+    for arg in (k_arg, k_arg + 2):
+        assert f"({arg}, {{}}, may-alias)" in alias.group(1), alias.group(1)
+    whole = f"bf16[{layers},{slots},{lines},"
+    ops = ["select(", "copy("]
+    if program == "_decode":
+        ops.append("dynamic-update-slice(")
+    offending = [ln.strip()[:200] for ln in txt.splitlines()
+                 if " = " in ln
+                 and ln.split(" = ", 1)[1].startswith(whole)
+                 and any(op in ln.split(" = ", 1)[1] for op in ops)]
+    assert not offending, offending

@@ -1,4 +1,4 @@
-"""Qwen2 1.5B — GQA kv=2, QKV bias [arXiv:2407.10671; hf]."""
+"""Qwen2 1.5B — GQA kv=2, QKV bias, theta 1e6, eps 1e-6 [arXiv:2407.10671; hf]."""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -11,6 +11,8 @@ CONFIG = ModelConfig(
     d_ff=8960,
     vocab_size=151936,
     qkv_bias=True,
+    rope_theta=1e6,
+    norm_eps=1e-6,
     tie_embeddings=True,    # qwen2 small models tie embeddings
     optimizer="adamw",
 )

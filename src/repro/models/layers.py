@@ -133,17 +133,19 @@ class exact_tp_scope:
 
 def gather_exact_tp(x):
     """All-gather x to replicated when tracing under exact_tp_scope (the
-    pre-contraction gather of the exact-TP combine); identity otherwise."""
+    pre-contraction gather of the exact-TP combine), under the named scope
+    `tp.gather`; identity otherwise."""
     if _EXACT_TP_MESH is None:
         return x
     from jax.sharding import NamedSharding, PartitionSpec
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(_EXACT_TP_MESH, PartitionSpec()))
+    with jax.named_scope("tp.gather"):
+        return jax.lax.with_sharding_constraint(
+            x, NamedSharding(_EXACT_TP_MESH, PartitionSpec()))
 
 
 def isolate_exact_tp(x):
-    """Pass x through an optimization barrier when tracing under
-    exact_tp_scope; identity otherwise.
+    """Pass x through an optimization barrier, under the named scope
+    `tp.fence`, when tracing under exact_tp_scope; identity otherwise.
 
     Bit-exactness across TP degrees also needs every device to compute an
     op the same way whatever its local width. On TPU, XLA picks fusions
@@ -157,7 +159,8 @@ def isolate_exact_tp(x):
     the dots), and TP4 and TP2 then match bit for bit."""
     if _EXACT_TP_MESH is None:
         return x
-    return jax.lax.optimization_barrier(x)
+    with jax.named_scope("tp.fence"):
+        return jax.lax.optimization_barrier(x)
 
 
 def matmul_rp(x, w):

@@ -306,6 +306,12 @@ class ServeEngine:
             # problem, not the global shape)
             self._ctx = DistCtx(mesh=mesh, data_axes=(), model_axis="model")
             self._cache_bytes_local = self._local_cache_bytes()
+            # the cache is made where it lives: each device zeroes only its
+            # own shards. Made whole on one device and then split, it must
+            # fit there beside that device's weights, and codeqwen1.5-7b's
+            # (4.3 GB over four chips) does not.
+            self._zero_cache_sharded = jax.jit(
+                self._zero_cache, out_shardings=self._specs.cache)
         else:
             self._specs = None
             self._ctx = None
@@ -470,13 +476,16 @@ class ServeEngine:
                 return b
         return n
 
-    def _fresh_cache(self):
+    def _zero_cache(self):
         cache = self.model.init_cache(self.max_batch, self.cache_len)
         # per-row positions: each slot decodes at its own offset
         cache["pos"] = jnp.zeros((self.max_batch,), jnp.int32)
-        if self._specs is not None:
-            cache = jax.device_put(cache, self._specs.cache)
         return cache
+
+    def _fresh_cache(self):
+        if self._specs is None:
+            return self._zero_cache()
+        return self._zero_cache_sharded()
 
     # ------------------------------------------------------------ per-device
 

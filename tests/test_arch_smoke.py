@@ -79,7 +79,7 @@ def test_param_counts_match_published():
     expected = {
         "qwen2_1_5b": 1.54e9,
         "phi4_mini_3_8b": 3.8e9,
-        "codeqwen1_5_7b": 8.2e9,   # from the ASSIGNED config (d_ff=13440, MHA); hf release is 7.25B
+        "codeqwen1_5_7b": 7.25e9,  # hf release: GQA-4, d_ff 13440, untied
         "qwen2_5_32b": 32.5e9,
         "rwkv6_7b": 7.6e9,
         "deepseek_moe_16b": 16.4e9,
@@ -95,3 +95,22 @@ def test_moe_active_params():
     assert cfg.active_param_count() < 0.05 * cfg.param_count()
     ds = get_config("deepseek_moe_16b")
     assert 2e9 < ds.active_param_count() < 4e9   # ~2.8B active (paper)
+
+
+@pytest.mark.parametrize("arch, published", [
+    ("codeqwen1_5_7b", dict(n_layers=32, d_model=4096, n_heads=32, n_kv_heads=4,
+                            head_dim=128, d_ff=13440, vocab_size=92416,
+                            qkv_bias=True, rope_theta=1e6, norm_eps=1e-5,
+                            tie_embeddings=False)),
+    ("qwen2_1_5b", dict(n_layers=28, d_model=1536, n_heads=12, n_kv_heads=2,
+                        head_dim=128, d_ff=8960, vocab_size=151936,
+                        qkv_bias=True, rope_theta=1e6, norm_eps=1e-6,
+                        tie_embeddings=True)),
+])
+def test_qwen_configs_state_the_published_model(arch, published):
+    """The Qwen configs the chip benchmark serves hold the values of the
+    published config.json (benchmarks/chip/configs/ states the same)."""
+    cfg = get_config(arch)
+    assert {k: getattr(cfg, k) for k in published} == published
+    if arch == "codeqwen1_5_7b":
+        assert abs(cfg.param_count() - 7.25e9) / 7.25e9 < 0.02

@@ -130,12 +130,33 @@ def test_exact_tp_fences_only_inside_scope(rows):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_exact_tp_scopes_name_the_gathers_and_fences():
+    """Under exact_tp_scope the row-parallel projection's gather and its
+    fences carry the named scopes `tp.gather` and `tp.fence` into the
+    lowered program's locations (what a profile's op names read);
+    outside the scope neither name appears."""
+    import jax.numpy as jnp
+    from repro.models.layers import exact_tp_scope, matmul_rp
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 1, 64), jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(1), (64, 32), jnp.bfloat16)
+
+    def text():     # a fresh function per trace: the scope is trace-time
+        return jax.jit(lambda x, w: matmul_rp(x, w)).lower(x, w).as_text(
+            debug_info=True)
+
+    plain = text()
+    with exact_tp_scope(make_mesh((1,), ("model",))):
+        scoped = text()
+    assert "tp.gather" in scoped and "tp.fence" in scoped
+    assert "tp.gather" not in plain and "tp.fence" not in plain
+
+
 # ------------------------------------------------- multi-device (subprocess)
 
-def _run_sub(code: str):
+def _run_sub(code: str, devices: int = 8):
     src = os.path.join(REPO_ROOT, "src")
     env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH=(src + os.pathsep + os.environ["PYTHONPATH"]
                            if os.environ.get("PYTHONPATH") else src))
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
@@ -258,3 +279,88 @@ def test_bench_serve_mesh_emits_per_device_rows(tmp_path):
     assert len(sizes) == 1
     # and the engine row is still there for the serve-smoke comparisons
     assert rows["serve_engine"]["metrics"]["occupancy"] > 0
+
+
+# A CodeQwen1.5-shaped decoder at a small size: GQA 8:1 with 4 K/V heads
+# (one K/V head and 8 query heads per chip on a 4-way `model` axis), QKV
+# bias, theta 1e6, untied head; the benchmark's reference and weights.
+CODEQWEN_SMALL = """
+CFG = {"name": "codeqwen-small", "num_hidden_layers": 2, "hidden_size": 512,
+       "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 16,
+       "intermediate_size": 512, "vocab_size": 512,
+       "tie_word_embeddings": False, "qkv_bias": True, "rope_theta": 1e6,
+       "rms_norm_eps": 1e-5, "init_std": 0.02, "init_norm_std": 0.1,
+       "init_bias_std": 0.02}
+"""
+
+
+def test_tp4_gqa4_engine_matches_the_f32_reference():
+    """A TP4 ServeEngine serving a CodeQwen1.5-shaped model (4 forced
+    host devices) prefills, then decodes through its head-sharded cache,
+    with logits within TOL of the float32 reference's full forward at
+    every served position; the reference's fp8 control misses TOL; and
+    the TP4 tokens are the mesh-less engine's.
+
+    TOL = 0.04 on the logits: served logits (|logit| < 2 here) come out
+    of bf16 activations with f32 accumulation, one bf16 step there is
+    2**-7, and the largest gap seen over two seeds was 0.013; the fp8
+    control (e4m3 projections, K/V and head) misses by 0.15-0.17."""
+    out = _run_sub(CODEQWEN_SMALL + textwrap.dedent("""
+        import jax, jax.numpy as jnp, numpy as np
+        from benchmarks.chip.adapters import dense_lm as adapter
+        from benchmarks.chip.reference import dense_lm as ref
+        from repro.launch.mesh import make_mesh
+        from repro.serve.engine import Request, ServeEngine
+        TOL, SEED = 0.04, 15
+        mcfg = adapter.model_config(CFG)
+        params = jax.jit(lambda kd: adapter.to_program(
+            ref.all_weights(CFG, kd)))(ref.key_data(SEED))
+        rng = np.random.default_rng(SEED)
+        prompts = [rng.integers(0, 512, n).astype(np.int32)
+                   for n in (5, 11, 17, 9)]
+        def mk():
+            return [Request(rid=i, prompt=p, max_new_tokens=8)
+                    for i, p in enumerate(prompts)]
+        plain = ServeEngine(mcfg, params, max_batch=2, cache_len=64).run(mk())
+        eng = ServeEngine(mcfg, params, max_batch=2, cache_len=64,
+                          mesh=make_mesh((4,), ("model",)))
+        kv = eng._specs.cache["k"].spec
+        assert tuple(kv)[3] == "model", kv          # one K/V head per chip
+        # the logits each sampled token came from: (rid, token index)
+        served, sample = {}, eng._sample_rows
+        def record(logits, slots):
+            rows = np.asarray(logits, np.float32).reshape(len(slots), -1)
+            for s, row in zip(slots, rows):
+                if s is not None:
+                    served.setdefault((s.rid, s.n_gen), row)
+            return sample(logits, slots)
+        eng._sample_rows = record
+        out = eng.run(mk())
+        fc, kd = ref._freeze(CFG), jnp.asarray(ref.key_data(SEED))
+        g = ref._gen_globals(fc, kd)
+        def forward(tokens, control):
+            x = g["embed"].astype(jnp.float32)[jnp.asarray(tokens)][None]
+            for layer in range(CFG["num_hidden_layers"]):
+                x = ref._apply_layer(fc, control,
+                                     ref._gen_layer(fc, kd, jnp.int32(layer)), x)
+            with jax.default_matmul_precision("highest"):
+                h = ref._rms(x[0], g["final_norm"].astype(jnp.float32),
+                             CFG["rms_norm_eps"])
+                return np.asarray(ref._mm(h, g["head"].astype(jnp.float32),
+                                          control))
+        gap, control_gap = 0.0, 0.0
+        for rid, p in enumerate(prompts):
+            assert len(out[rid]) == 8
+            toks = np.concatenate([p, np.asarray(out[rid][:-1], np.int32)])
+            r, c = forward(toks, False), forward(toks, True)
+            for j in range(8):
+                pos = len(p) - 1 + j
+                gap = max(gap, float(np.max(np.abs(served[rid, j] - r[pos]))))
+                control_gap = max(control_gap,
+                                  float(np.max(np.abs(c[pos] - r[pos]))))
+        assert gap <= TOL, (gap, TOL)
+        assert control_gap > TOL, (control_gap, TOL)
+        assert out == plain, (out, plain)
+        print("gap", gap, "control", control_gap, "tp4 == mesh-less")
+    """), devices=4)
+    assert "tp4 == mesh-less" in out

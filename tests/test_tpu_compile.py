@@ -15,7 +15,12 @@ compiled program holds the Pallas kernel (`tpu_custom_call`):
   * the serve engine's decode and slot-prefill steps at qwen2-1.5b widths
     (64 slots of 2048 lines), which alias the cache they are handed and
     write their new lines in place: no whole-cache select or copy, and in
-    decode no whole-cache dynamic-update-slice.
+    decode no whole-cache dynamic-update-slice;
+  * the same two steps of codeqwen1.5-7b, whole at its published widths,
+    tensor-parallel over the four chips of the v5e:2x2 (the serve plan's
+    exact-TP layout, one K/V head per chip): they fit a chip and alias
+    the cache shards they are handed, and each chip makes only its own
+    shard of the cache.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and test workers import every
@@ -198,3 +203,67 @@ def test_serve_steps_write_the_cache_in_place(one_chip, program):
                  and ln.split(" = ", 1)[1].startswith(whole)
                  and any(op in ln.split(" = ", 1)[1] for op in ops)]
     assert not offending, offending
+
+
+TP4_SLOTS, TP4_LINES = 32, 2048
+
+
+def _tp4_codeqwen_engine(topo, monkeypatch):
+    """(engine, model) of codeqwen1.5-7b (32 layers, d 4096, 32/4 heads of
+    128, ff 13440, vocab 92416, untied) over a `model` mesh of the four
+    described chips, 32 slots of 2048 lines."""
+    from repro.configs.base import get_config
+    from repro.launch.mesh import make_mesh
+    from repro.models.registry import build_model
+    from repro.serve.engine import ServeEngine
+    cfg = get_config("codeqwen1.5-7b")
+    mesh = make_mesh((4,), ("model",), devices=topo.devices)
+    # shapes, not arrays: the engine's placement of its weights onto the
+    # mesh has no described device to put them on
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    eng = ServeEngine(cfg, None, max_batch=TP4_SLOTS, cache_len=TP4_LINES,
+                      mesh=mesh)
+    return eng, build_model(cfg)
+
+
+@pytest.mark.parametrize("program", ["_decode", "_prefill_slot"])
+def test_tp4_codeqwen_steps_fit_and_donate(topo, monkeypatch, program):
+    eng, model = _tp4_codeqwen_engine(topo, monkeypatch)
+    sp, slots = eng._specs, TP4_SLOTS
+
+    def shaped(tree, shardings):
+        return jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s), tree,
+                            shardings)
+
+    params = shaped(model.abstract_params(), sp.params)
+    cache = model.init_cache(slots, TP4_LINES, abstract=True)
+    cache["pos"] = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    cache = shaped(cache, sp.cache)
+    assert tuple(sp.cache["k"].spec)[3] == "model"    # one K/V head a chip
+    rep = sp.replicated
+    if program == "_decode":
+        args = (_sds((slots, 1), jnp.int32, rep), _sds((slots,), jnp.bool_, rep))
+    else:
+        i32 = _sds((), jnp.int32, rep)
+        args = (i32, {"tokens": _sds((1, 512), jnp.int32, rep)}, i32)
+    compiled = getattr(eng, program).lower(params, cache, *args).compile()
+    mem = compiled.memory_analysis()
+    per_chip = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert per_chip < 16e9, per_chip
+    txt = compiled.as_text()
+    k_arg = len(jax.tree.leaves(params))
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry", txt.splitlines()[0])
+    assert alias, "the program aliases none of its arguments"
+    for arg in (k_arg, k_arg + 2):
+        assert f"({arg}, {{}}, may-alias)" in alias.group(1), alias.group(1)
+
+
+def test_tp4_codeqwen_cache_is_made_in_its_shards(topo, monkeypatch):
+    """Each chip zeroes only its quarter of the K/V (one head of four):
+    made whole on one chip first, the 4.3 GB cache does not fit there
+    beside that chip's 7.6 GB of weights."""
+    eng, _ = _tp4_codeqwen_engine(topo, monkeypatch)
+    mem = eng._zero_cache_sharded.lower().compile().memory_analysis()
+    quarter = 2 * 32 * TP4_SLOTS * TP4_LINES * 1 * 128 * 2   # K and V, bf16
+    assert quarter <= mem.output_size_in_bytes < quarter + 4096
+    assert mem.temp_size_in_bytes < 4096

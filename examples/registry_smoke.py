@@ -27,7 +27,8 @@ def main():
     backend.enable_compile_cache()
     names = repro.list_kernels()
     print(f"registered kernels: {names}")
-    assert {"gpp", "flash", "ssm", "paged_decode"} <= set(names), names
+    assert {"gpp", "flash", "ssm", "paged_decode", "live_decode"} <= set(names), \
+        names
 
     # gpp at TINY vs the complex128 oracle
     from repro.kernels.gpp import problem, ref
@@ -60,6 +61,15 @@ def main():
     pd = repro.dispatch("paged_decode", *pargs, interpret=True, **pkw)
     pd_ref = repro.dispatch("paged_decode", *pargs, version="ref", **pkw)
     check("paged_decode gather@16x4", (pd,), (pd_ref,), atol=1e-2)
+
+    # live_decode: the live-line Pallas decode vs decode_attention over
+    # the layer's whole slab
+    from repro.kernels.live_decode.kernel_def import LiveDecodeKey
+    lkey = LiveDecodeKey(b=3, h=4, kvh=2, n_layers=2, lines=64, hd=32)
+    largs, _ = api.get_kernel("live_decode").make_example(lkey)
+    ld = repro.dispatch("live_decode", *largs, interpret=True)
+    ld_ref = repro.dispatch("live_decode", *largs, version="ref")
+    check("live_decode pallas@64", (ld,), (ld_ref,), atol=2e-2)
 
     print("registry smoke: all kernels dispatch and match their references")
 

@@ -201,6 +201,7 @@ def _ensure_builtins() -> None:
         return
     from repro.kernels.flash import kernel_def as _f    # noqa: F401
     from repro.kernels.gpp import kernel_def as _g      # noqa: F401
+    from repro.kernels.live_decode import kernel_def as _l  # noqa: F401
     from repro.kernels.paged import kernel_def as _p    # noqa: F401
     from repro.kernels.ssm import kernel_def as _s      # noqa: F401
     _BUILTINS_LOADED = True

@@ -261,6 +261,33 @@ def flash_attention_spmd(q, k, v, ctx: Optional[DistCtx], *,
     return fn(q, k, v)
 
 
+def live_decode_attention(q, cache_k, cache_v, layer, lens,
+                          ctx: Optional[DistCtx] = None) -> jax.Array:
+    """Single-token decode against layer `layer` of the stacked caches,
+    reading only each row's live lines: the registry's `live_decode`
+    Pallas kernel. q: (B,1,H,Hd); caches: (n_layers,B,L,KvH,Hd); lens:
+    (B,) live lines per row (0: the row reads nothing and gets zeros).
+    `decode_attention` over `cache[layer]` is its reference.
+
+    On a mesh the kernel runs under shard_map with heads on `model` where
+    the mesh divides them (the serve plan's cache layout, every other
+    dim whole), so each device walks only its own K/V heads' lines:
+    under GSPMD a Pallas call would gather its operands."""
+    from repro.kernels import api
+    h, kvh = q.shape[2], cache_k.shape[3]
+    run = lambda q_, k_, v_, l_, n_: api.dispatch(  # noqa: E731
+        "live_decode", q_, k_, v_, l_, n_)
+    if ctx is None or ctx.mesh is None:
+        return run(q[:, 0], cache_k, cache_v, layer, lens)[:, None]
+    tp = ctx.mesh.shape[ctx.model_axis]
+    hspec = ctx.model_axis if (h % tp == 0 and kvh % tp == 0) else None
+    qs, cs = P(None, hspec, None), P(None, None, None, hspec, None)
+    fn = jax.shard_map(
+        run, mesh=ctx.mesh, in_specs=(qs, cs, cs, P(), P()), out_specs=qs,
+        axis_names=frozenset(ctx.mesh.axis_names), check_vma=False)
+    return fn(q[:, 0], cache_k, cache_v, layer, lens)[:, None]
+
+
 def _dispatch_flash(q, k, v, causal):
     """Registry dispatch with a model-only tuned config (no timing pass at
     trace time); shapes the tune menu can't tile fall back to config=None,

@@ -135,11 +135,12 @@ def _dense_prefill_stack(cfg: ModelConfig, layers, x, positions, *,
 
 
 def _dense_decode_stack(cfg: ModelConfig, layers, x, cache, *, ctx,
-                        window: int = 0, ring: bool = False):
+                        window: int = 0, ring: bool = False, active=None):
     """One decode token through dense/moe layers. The stacked K/V caches
     ride the layer loop's carry, so each layer writes only its new lines
     (T.attn_block_decode); as scan inputs and outputs they would be
-    rebuilt whole every step."""
+    rebuilt whole every step. active: optional (B,) bool of the rows
+    whose output is used (T.attn_block_decode)."""
     pos = cache["pos"]
 
     def body(carry, inp):
@@ -148,7 +149,8 @@ def _dense_decode_stack(cfg: ModelConfig, layers, x, cache, *, ctx,
         h = rms_norm(x, lp["ln1"], cfg.norm_eps)
         a, (ks, vs) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ks,
                                           cache_v=vs, layer=i, pos=pos,
-                                          window=window, ctx=ctx, ring=ring)
+                                          window=window, ctx=ctx, ring=ring,
+                                          active=active)
         x = x + a
         h = rms_norm(x, lp["ln2"], cfg.norm_eps)
         if "moe" in lp:
@@ -663,11 +665,15 @@ def build_model(cfg: ModelConfig) -> Model:
 
     # ---- decode --------------------------------------------------------------
 
-    def decode_step(params, cache, tokens, ctx: Optional[DistCtx] = None):
+    def decode_step(params, cache, tokens, ctx: Optional[DistCtx] = None,
+                    active=None):
         """tokens: (B, 1). Returns (logits (B,1,V) f32, new cache).
 
         cache["pos"] may be a scalar (lockstep decode) or a (B,) vector
-        (slot scheduler: every row at its own offset)."""
+        (slot scheduler: every row at its own offset). active: optional
+        (B,) bool of the rows whose logits the caller uses; the dense
+        stacks' attention may skip the others' cache lines, and their
+        logits are then meaningless (T.attn_block_decode)."""
         x = embed(tokens, params["embed"])
         if cfg.family == "encdec":
             pe = params["dec_pos"][cache["pos"]].astype(x.dtype)
@@ -677,17 +683,17 @@ def build_model(cfg: ModelConfig) -> Model:
                                           decode=True, ctx=ctx)
         elif cfg.family in ("dense", "vlm"):
             x, cache = _dense_decode_stack(cfg, params["layers"], x, cache,
-                                           ctx=ctx)
+                                           ctx=ctx, active=active)
         elif cfg.family == "moe":
             pos = cache["pos"]
             if cfg.first_k_dense:
                 dsub = {"k": cache["dk"], "v": cache["dv"], "pos": pos}
                 x, dsub = _dense_decode_stack(cfg, params["dense_layers"], x,
-                                              dsub, ctx=ctx)
+                                              dsub, ctx=ctx, active=active)
                 cache = {**cache, "dk": dsub["k"], "dv": dsub["v"]}
             sub = {"k": cache["k"], "v": cache["v"], "pos": pos}
             x, sub = _dense_decode_stack(cfg, params["layers"], x, sub,
-                                         ctx=ctx)
+                                         ctx=ctx, active=active)
             cache = {**cache, "k": sub["k"], "v": sub["v"], "pos": pos + 1}
         elif cfg.family == "ssm":
             x, cache = _rwkv_stack(cfg, params["layers"], x, cache,

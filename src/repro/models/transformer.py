@@ -26,6 +26,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import backend
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
@@ -296,9 +297,19 @@ def write_kv_lines(cache, layer, pos, new):
                                             mode="drop")
 
 
+def reads_live_lines(per_row: bool, window: int, ring: bool) -> bool:
+    """Whether one-token attention runs the `live_decode` kernel, which
+    reads only each row's live lines: per-row positions (the slot
+    scheduler), no window, no ring, on a TPU. Everywhere else
+    `decode_attention` reads the layer's whole slab and masks; on the
+    CPU the kernel would only run interpreted."""
+    return per_row and not window and not ring and backend.on_tpu()
+
+
 def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
                       pos, window=0, rope=True,
-                      ctx: Optional[DistCtx] = None, ring=False):
+                      ctx: Optional[DistCtx] = None, ring=False,
+                      active=None):
     """One-token attention against layer `layer` of the stacked caches
     cache_k/v: (n_layers,B,L,KvH,Hd). Returns (out, (cache_k, cache_v))
     with this token's K/V line written.
@@ -307,7 +318,9 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
     lockstep decode, one dynamic_update_slice) or a (B,) vector when every
     batch row is at its own offset (the serve engine's slot scheduler,
     where refilled slots join mid-flight): each row's line then lands at
-    its own position (write_kv_lines)."""
+    its own position (write_kv_lines). active: optional (B,) bool of the
+    rows whose output is used; where the live-line kernel runs
+    (reads_live_lines), the other rows read no line and get zeros."""
     b_, s, _ = x.shape
     assert s == 1
     per_row = jnp.ndim(pos) == 1
@@ -327,19 +340,27 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
         at = (layer, 0, slot, 0, 0)
         cache_k = jax.lax.dynamic_update_slice(cache_k, k[None], at)
         cache_v = jax.lax.dynamic_update_slice(cache_v, v[None], at)
-    ck, cv = cache_k[layer], cache_v[layer]
     cache_len = pos + 1
-    if ring:
-        # ring buffer (sliding window): every slot <= cache_len-1 is valid;
-        # window masking is implicit in the buffer size
-        eff_len = jnp.minimum(cache_len, lcache)
-        out = _attend(attn_lib.decode_attention, q, ck, cv, eff_len)
-    elif ctx is not None and ctx.kv_seq_shard and not per_row:
-        out = attn_lib.flash_decode_sharded(q, ck, cv, cache_len,
-                                            ctx=ctx, window=window)
+    if reads_live_lines(per_row, window, ring):
+        # the kernel takes the stacked caches whole: a slice of one layer
+        # would itself be a read of every line
+        lens = cache_len if active is None else \
+            jnp.where(active, cache_len, 0)
+        out = isolate_exact_tp(attn_lib.live_decode_attention(
+            isolate_exact_tp(q), cache_k, cache_v, layer, lens, ctx))
     else:
-        out = _attend(attn_lib.decode_attention, q, ck, cv, cache_len,
-                      window=window)
+        ck, cv = cache_k[layer], cache_v[layer]
+        if ring:
+            # ring buffer (sliding window): every slot <= cache_len-1 is
+            # valid; window masking is implicit in the buffer size
+            eff_len = jnp.minimum(cache_len, lcache)
+            out = _attend(attn_lib.decode_attention, q, ck, cv, eff_len)
+        elif ctx is not None and ctx.kv_seq_shard and not per_row:
+            out = attn_lib.flash_decode_sharded(q, ck, cv, cache_len,
+                                                ctx=ctx, window=window)
+        else:
+            out = _attend(attn_lib.decode_attention, q, ck, cv, cache_len,
+                          window=window)
     out = matmul_rp(out.reshape(b_, 1, -1), lp["wo"])
     return out, (cache_k, cache_v)
 

@@ -318,6 +318,16 @@ class ServeEngine:
             self.params = params
             self._cache_bytes_local = 0
 
+        # the block of the live-line decode kernel where the dense stacks'
+        # per-row decode runs it (transformer.reads_live_lines); 0 where
+        # each decode step reads every line of every slot
+        from repro.models.transformer import reads_live_lines
+        self._kv_blk = 0
+        if cfg.family in ("dense", "moe", "vlm") and \
+                reads_live_lines(True, 0, False):
+            from repro.kernels.live_decode.kernel_def import block_for
+            self._kv_blk = block_for(cache_len)
+
         from repro.models.layers import exact_tp_scope
 
         # the jitted functions' names are the compiled modules' names
@@ -329,7 +339,8 @@ class ServeEngine:
             # mesh=None makes it a no-op.
             with jax.named_scope("serve.decode"):
                 with exact_tp_scope(mesh):
-                    logits, new = self.model.decode_step(p, c, t, self._ctx)
+                    logits, new = self.model.decode_step(p, c, t, self._ctx,
+                                                         active=active)
                 # done-row masking: hold finished slots' pos so their
                 # cache rows stop growing — the step writes one (masked,
                 # invisible) line at the held position and the row costs
@@ -383,7 +394,8 @@ class ServeEngine:
                     f"entry (spec_k>0 needs one): {cfg.family!r}")
 
             def _draft_decode(p, c, t, active):
-                logits, new = self.draft_model.decode_step(p, c, t, None)
+                logits, new = self.draft_model.decode_step(p, c, t, None,
+                                                           active=active)
                 new["pos"] = jnp.where(active, new["pos"], c["pos"])
                 return logits, new
 
@@ -651,6 +663,8 @@ class ServeEngine:
         self._n_prefill_bucket_tokens = 0
         self._n_submitted = 0
         self._slot_steps_active = 0
+        self._kv_lines_read = 0
+        self._kv_lines_full = 0
         self._t_start = time.perf_counter()
 
     @property
@@ -762,6 +776,7 @@ class ServeEngine:
                                                jnp.asarray(active))
         self._n_steps += 1
         self._slot_steps_active += int(active.sum())
+        self._count_kv_lines(self._kv_blk)
         toks = self._sample_rows(logits, self._slots)
         for i, s in enumerate(self._slots):
             if s is None:
@@ -782,6 +797,20 @@ class ServeEngine:
         return StepReport(admitted=admitted, finished=finished,
                           decoded=int(active.sum()),
                           queue_depth=len(self._queue))
+
+    def _count_kv_lines(self, blk: int) -> None:
+        """Count one decode step's K/V lines a layer: those it reads, each
+        active row's lines rounded up to `blk` (every line of every slot
+        where blk is 0), and those a whole-cache read takes. A row about
+        to decode holds prompt_len + n_gen lines, its new one included."""
+        full = self.max_batch * self.cache_len
+        self._kv_lines_full += full
+        if not blk:
+            self._kv_lines_read += full
+            return
+        self._kv_lines_read += sum(
+            -(-(s.prompt_len + s.n_gen) // blk) * blk
+            for s in self._slots if s is not None)
 
     def _spec_round(self, admitted: List[int], finished: List[int]
                     ) -> StepReport:
@@ -841,6 +870,7 @@ class ServeEngine:
         vlg = np.asarray(vlg, np.float32)
         self._n_steps += 1
         self._slot_steps_active += n_active
+        self._count_kv_lines(0)      # the verify reads every line
         self._spec["verify_steps"] += n_active
         self._spec["draft_steps"] += n_active * (k + 1)
         self._spec["proposed"] += n_active * k
@@ -966,6 +996,10 @@ class ServeEngine:
         # and the tokens of the padded buckets that prefill computed
         engine_stats["prefill_tokens"] = self._n_prefill_tokens
         engine_stats["prefill_bucket_tokens"] = self._n_prefill_bucket_tokens
+        # K/V lines a layer the decode steps read, and those whole-cache
+        # reads would take
+        engine_stats["kv_lines_read"] = self._kv_lines_read
+        engine_stats["kv_lines_full"] = self._kv_lines_full
         if self._kv is not None:
             # merged outside the pinned schema, like the counters above
             engine_stats["kvcache"] = self._kv.stats()

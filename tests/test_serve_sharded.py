@@ -364,3 +364,44 @@ def test_tp4_gqa4_engine_matches_the_f32_reference():
         print("gap", gap, "control", control_gap, "tp4 == mesh-less")
     """), devices=4)
     assert "tp4 == mesh-less" in out
+
+
+@pytest.mark.slow
+def test_tp4_live_decode_route_serves_the_whole_slab_tokens():
+    """With the live-line decode kernel forced on (as on a TPU; it runs
+    interpreted here), a TP4 engine runs it under shard_map, one K/V head
+    and 8 query heads a device, and serves the tokens of the mesh-less
+    engine that reads whole slabs."""
+    out = _run_sub("""
+        import jax, numpy as np
+        from repro.configs.base import ModelConfig
+        from repro.launch.mesh import make_mesh
+        from repro.models import transformer as T
+        from repro.models.registry import build_model
+        from repro.serve.engine import Request, ServeEngine
+        cfg = ModelConfig(name="gqa8-small", family="dense", n_layers=2,
+                          d_model=512, n_heads=32, n_kv_heads=4, d_ff=512,
+                          vocab_size=256, head_dim=16, qkv_bias=True,
+                          rope_theta=1e6, norm_eps=1e-5,
+                          tie_embeddings=False, remat="none")
+        params = build_model(cfg).init_params(jax.random.PRNGKey(4))
+        rng = np.random.default_rng(4)
+        prompts = [rng.integers(0, 256, n) for n in (5, 23, 9)]
+        def mk():
+            return [Request(rid=i, prompt=p, max_new_tokens=(3, 8, 6)[i])
+                    for i, p in enumerate(prompts)]
+        want = ServeEngine(cfg, params, max_batch=2, cache_len=64).run(mk())
+        T.reads_live_lines = lambda per_row, window, ring: (
+            per_row and not window and not ring)
+        eng = ServeEngine(cfg, params, max_batch=2, cache_len=64,
+                          mesh=make_mesh((4,), ("model",)))
+        assert eng._kv_blk == 64
+        txt = eng._decode.lower(eng.params, eng._fresh_cache(),
+                                np.zeros((2, 1), np.int32),
+                                np.ones(2, bool)).as_text()
+        assert "shard_map" in txt or "sdy.manual_computation" in txt
+        out = eng.run(mk())
+        assert out == want, (out, want)
+        print("tp4 live route == whole slab")
+    """, devices=4)
+    assert "tp4 live route == whole slab" in out

@@ -159,18 +159,21 @@ def test_ssm_scan_compiles_at_hymba_widths(one_chip):
         assert "tpu_custom_call" in txt, cfg
 
 
-@pytest.mark.parametrize("program", ["_decode", "_prefill_slot"])
-def test_serve_steps_write_the_cache_in_place(one_chip, program):
-    """qwen2-1.5b widths with 2 layers, 64 slots of 2048 lines."""
+QWEN2_LAYERS, QWEN2_SLOTS, QWEN2_LINES = 2, 64, 2048
+
+
+def _qwen2_serve_step(one_chip, program):
+    """(compiled text, params) of the serve engine's `program` at
+    qwen2-1.5b widths with 2 layers, 64 slots of 2048 lines."""
     from repro.configs.base import ModelConfig
     from repro.models.registry import build_model
     from repro.serve.engine import ServeEngine
-    layers, slots, lines = 2, 64, 2048
-    cfg = ModelConfig(name="qwen2-1.5b-2l", family="dense", n_layers=layers,
-                      d_model=1536, n_heads=12, n_kv_heads=2, d_ff=8960,
-                      vocab_size=151936, head_dim=128, qkv_bias=True,
-                      rope_theta=1e6, norm_eps=1e-6, tie_embeddings=True,
-                      remat="none")
+    slots, lines = QWEN2_SLOTS, QWEN2_LINES
+    cfg = ModelConfig(name="qwen2-1.5b-2l", family="dense",
+                      n_layers=QWEN2_LAYERS, d_model=1536, n_heads=12,
+                      n_kv_heads=2, d_ff=8960, vocab_size=151936,
+                      head_dim=128, qkv_bias=True, rope_theta=1e6,
+                      norm_eps=1e-6, tie_embeddings=True, remat="none")
     model = build_model(cfg)
 
     def on_chip(tree):
@@ -188,21 +191,84 @@ def test_serve_steps_write_the_cache_in_place(one_chip, program):
         i32 = _sds((), jnp.int32, one_chip)
         args = (i32, {"tokens": _sds((1, 512), jnp.int32, one_chip)}, i32)
     txt = getattr(eng, program).lower(params, cache, *args).compile().as_text()
+    return txt, params
+
+
+def _assert_cache_aliased(txt, params):
     # arguments: the weight leaves, then the cache's k, pos and v
     k_arg = len(jax.tree.leaves(params))
     alias = re.search(r"input_output_alias=\{(.*?)\} *\}", txt.splitlines()[0])
     assert alias, "the program aliases none of its arguments"
     for arg in (k_arg, k_arg + 2):
         assert f"({arg}, {{}}, may-alias)" in alias.group(1), alias.group(1)
-    whole = f"bf16[{layers},{slots},{lines},"
+
+
+def _ops_making(txt, shape_prefix, ops):
+    """The compiled ops whose result starts with `shape_prefix` and that
+    are one of `ops`."""
+    return [ln.strip() for ln in txt.splitlines()
+            if " = " in ln
+            and ln.split(" = ", 1)[1].startswith(shape_prefix)
+            and any(op in ln.split(" = ", 1)[1] for op in ops)]
+
+
+@pytest.mark.parametrize("program", ["_decode", "_prefill_slot"])
+def test_serve_steps_write_the_cache_in_place(one_chip, program):
+    """qwen2-1.5b widths with 2 layers, 64 slots of 2048 lines."""
+    txt, params = _qwen2_serve_step(one_chip, program)
+    _assert_cache_aliased(txt, params)
     ops = ["select(", "copy("]
     if program == "_decode":
         ops.append("dynamic-update-slice(")
-    offending = [ln.strip()[:200] for ln in txt.splitlines()
-                 if " = " in ln
-                 and ln.split(" = ", 1)[1].startswith(whole)
-                 and any(op in ln.split(" = ", 1)[1] for op in ops)]
-    assert not offending, offending
+    whole = f"bf16[{QWEN2_LAYERS},{QWEN2_SLOTS},{QWEN2_LINES},"
+    offending = _ops_making(txt, whole, ops)
+    assert not offending, [ln[:200] for ln in offending]
+
+
+def _live_route_on(monkeypatch):
+    """The live-line decode route as on a TPU, its kernel compiled."""
+    from repro.models import transformer as T
+    monkeypatch.setattr(T, "reads_live_lines",
+                        lambda per_row, window, ring:
+                        per_row and not window and not ring)
+    monkeypatch.setenv("REPRO_INTERPRET", "0")
+
+
+@pytest.mark.parametrize("b,h,kvh,layers", [(64, 12, 2, 28), (32, 8, 1, 32)],
+                         ids=["qwen2-1.5b", "codeqwen-tp4-chip"])
+def test_live_decode_compiles_at_serve_widths(one_chip, b, h, kvh, layers):
+    """The kernel over a whole stacked cache of 2048 lines a slot: it
+    reads the cache where it lies, with no copy of it."""
+    from repro.kernels.live_decode import live_decode
+    cache = _sds((layers, b, 2048, kvh, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda q, k, v, layer, lens:
+                       live_decode.live_decode_attention(
+                           q, k, v, layer, lens, blk=256, interpret=False)
+                       ).lower(_sds((b, h, 128), jnp.bfloat16, one_chip),
+                               cache, cache,
+                               _sds((), jnp.int32, one_chip),
+                               _sds((b,), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+def test_serve_decode_reads_live_lines_where_the_cache_lies(one_chip,
+                                                            monkeypatch):
+    """With the live-line route on, the decode step hands the kernel the
+    stacked cache itself: no dynamic-slice of a layer's whole slab, no
+    copy, and the cache still aliased."""
+    _live_route_on(monkeypatch)
+    txt, params = _qwen2_serve_step(one_chip, "_decode")
+    assert "tpu_custom_call" in txt
+    _assert_cache_aliased(txt, params)
+    for whole in (f"bf16[{QWEN2_LAYERS},{QWEN2_SLOTS},{QWEN2_LINES},",
+                  f"bf16[1,{QWEN2_SLOTS},{QWEN2_LINES},",
+                  f"bf16[{QWEN2_SLOTS},{QWEN2_LINES},"):
+        offending = _ops_making(txt, whole, ["select(", "copy(",
+                                             "dynamic-slice", "fusion("])
+        # the two line scatters are the only ops that make a whole cache
+        offending = [ln[:200] for ln in offending if "scatter" not in ln]
+        assert not offending, offending
 
 
 TP4_SLOTS, TP4_LINES = 32, 2048

@@ -46,11 +46,6 @@ class Model:
     # prefill is not suffix-separable (recurrent state, vis/enc prefixes,
     # token-count-sensitive MoE capacity).
     prefill_continue: Optional[Callable[..., Tuple[jax.Array, PyTree]]] = None
-    # multi-position decode: verify Q=k+1 candidate tokens per row in ONE
-    # forward (speculative decoding — serve/spec.py). None for families
-    # whose step is not position-batchable (recurrent state folds tokens
-    # sequentially; moe capacity is token-count sensitive).
-    decode_verify: Optional[Callable[..., Tuple[jax.Array, PyTree]]] = None
 
 
 # ===========================================================================
@@ -88,80 +83,74 @@ def _moe_apply(cfg: ModelConfig, lp_moe, h, ctx, token_valid=None):
     return f.reshape(b_, s, d), aux
 
 
-def _dense_stack(cfg: ModelConfig, layers, x, positions, *, remat, moe: bool,
-                 window: int = 0, ctx=None):
+def _dense_block(cfg: ModelConfig, lp, x, attend, *, ctx,
+                 token_valid=None):
+    """One dense/moe decoder layer: RMSNorm -> attention -> residual ->
+    RMSNorm -> FFN -> residual, the FFN routed experts where the layer's
+    params hold them ("moe") and SwiGLU otherwise. attend(h) returns the
+    attention output and what that path carries out (fresh (k, v), or the
+    updated caches). token_valid: flattened (B*S,) bool keeping pad tokens
+    out of the MoE capacity competition. Returns (x, aux_loss, carried)."""
+    h = rms_norm(x, lp["ln1"], cfg.norm_eps)
+    a, carried = attend(h)
+    x = x + a
+    h = rms_norm(x, lp["ln2"], cfg.norm_eps)
+    if "moe" in lp:
+        f, aux = _moe_apply(cfg, lp["moe"], h, ctx, token_valid=token_valid)
+    else:
+        f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
+        aux = jnp.float32(0.0)
+    return x + f, aux, carried
+
+
+def _dense_stack(cfg: ModelConfig, layers, x, positions, *, remat, ctx=None):
     """Scan dense/moe decoder layers over x (B,S,D). Returns (x, aux_loss)."""
 
-    def body(carry, lp):
-        x = carry
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, _ = T.attn_block(lp["attn"], h, cfg, positions=positions,
-                            window=window, ctx=ctx)
-        x = x + a
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if moe:
-            f, aux = _moe_apply(cfg, lp["moe"], h, ctx)
-        else:
-            f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-            aux = jnp.float32(0.0)
-        return x + f, aux
+    def body(x, lp):
+        x, aux, _ = _dense_block(
+            cfg, lp, x, lambda h: T.attn_block(lp["attn"], h, cfg,
+                                               positions=positions, ctx=ctx),
+            ctx=ctx)
+        return x, aux
 
-    body = _maybe_remat(body, remat)
-    x, auxs = jax.lax.scan(body, x, layers)
+    x, auxs = jax.lax.scan(_maybe_remat(body, remat), x, layers)
     return x, jnp.sum(auxs)
 
 
-def _dense_prefill_stack(cfg: ModelConfig, layers, x, positions, *,
-                         moe: bool, window: int = 0, ctx=None,
+def _dense_prefill_stack(cfg: ModelConfig, layers, x, positions, *, ctx=None,
                          kv_valid=None):
     """Like _dense_stack but also emits the (k, v) cache per layer."""
+    tv = None if kv_valid is None else kv_valid.reshape(-1)
 
-    def body(carry, lp):
-        x = carry
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, (k, v) = T.attn_block(lp["attn"], h, cfg, positions=positions,
-                                 window=window, ctx=ctx, kv_valid=kv_valid)
-        x = x + a
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if moe:
-            tv = None if kv_valid is None else kv_valid.reshape(-1)
-            f, _ = _moe_apply(cfg, lp["moe"], h, ctx, token_valid=tv)
-        else:
-            f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-        return x + f, (k, v)
+    def body(x, lp):
+        x, _, kv = _dense_block(
+            cfg, lp, x, lambda h: T.attn_block(lp["attn"], h, cfg,
+                                               positions=positions, ctx=ctx,
+                                               kv_valid=kv_valid),
+            ctx=ctx, token_valid=tv)
+        return x, kv
 
     x, (ks, vs) = jax.lax.scan(body, x, layers)
     return x, ks, vs
 
 
-def _dense_decode_stack(cfg: ModelConfig, layers, x, cache, *, ctx,
-                        window: int = 0, ring: bool = False, active=None):
-    """One decode token through dense/moe layers. The stacked K/V caches
-    ride the layer loop's carry, so each layer writes only its new lines
-    (T.attn_block_decode); as scan inputs and outputs they would be
-    rebuilt whole every step. active: optional (B,) bool of the rows
-    whose output is used (T.attn_block_decode)."""
-    pos = cache["pos"]
+def _cached_stack(cfg: ModelConfig, layers, x, cache_k, cache_v, attend, ctx):
+    """Dense/moe layers over x with the stacked (n_layers, ...) K/V caches
+    in the layer loop's carry, so each layer writes only its new lines; as
+    scan inputs and outputs the caches would be rebuilt whole every call.
+    attend(lp_attn, h, cache_k, cache_v, layer) -> (out, (cache_k,
+    cache_v)). Returns (x, cache_k, cache_v)."""
 
     def body(carry, inp):
         x, ks, vs = carry
         lp, i = inp
-        h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-        a, (ks, vs) = T.attn_block_decode(lp["attn"], h, cfg, cache_k=ks,
-                                          cache_v=vs, layer=i, pos=pos,
-                                          window=window, ctx=ctx, ring=ring,
-                                          active=active)
-        x = x + a
-        h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if "moe" in lp:
-            f, _ = _moe_apply(cfg, lp["moe"], h, ctx)
-        else:
-            f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-        return (x + f, ks, vs), None
+        x, _, (ks, vs) = _dense_block(
+            cfg, lp, x, lambda h: attend(lp["attn"], h, ks, vs, i), ctx=ctx)
+        return (x, ks, vs), None
 
-    (x, ks, vs), _ = jax.lax.scan(body, (x, cache["k"], cache["v"]),
-                                  (layers, _layer_ids(cache["k"])))
-    return x, {**cache, "k": ks, "v": vs, "pos": pos + 1}
+    (x, ks, vs), _ = jax.lax.scan(body, (x, cache_k, cache_v),
+                                  (layers, _layer_ids(cache_k)))
+    return x, ks, vs
 
 
 def _layer_ids(stacked):
@@ -495,17 +484,12 @@ def build_model(cfg: ModelConfig) -> Model:
             x = jnp.concatenate([batch["vis"].astype(x.dtype), x], axis=1)
         positions = jnp.arange(x.shape[1])
 
-        if cfg.family in ("dense", "vlm"):
-            x, aux = _dense_stack(cfg, params["layers"], x, positions,
-                                  remat=cfg.remat, moe=False, ctx=ctx)
-        elif cfg.family == "moe":
-            aux = jnp.float32(0.0)
-            if cfg.first_k_dense:
+        if cfg.family in ("dense", "vlm", "moe"):
+            if "dense_layers" in params:   # moe's leading dense layers
                 x, _ = _dense_stack(cfg, params["dense_layers"], x, positions,
-                                    remat=cfg.remat, moe=False)
-            x, aux2 = _dense_stack(cfg, params["layers"], x, positions,
-                                   remat=cfg.remat, moe=True, ctx=ctx)
-            aux = aux + aux2
+                                    remat=cfg.remat)
+            x, aux = _dense_stack(cfg, params["layers"], x, positions,
+                                  remat=cfg.remat, ctx=ctx)
         elif cfg.family == "ssm":
             L, b_, d = cfg.n_layers, x.shape[0], cfg.d_model
             h = d // cfg.rwkv_head_dim
@@ -629,22 +613,15 @@ def build_model(cfg: ModelConfig) -> Model:
                 jnp.arange(s)[None, :] - pad_lens[:, None], 0)
             kv_valid = jnp.arange(s)[None, :] >= pad_lens[:, None]
 
-        if cfg.family in ("dense", "vlm"):
-            x, ks, vs = _dense_prefill_stack(cfg, params["layers"], x,
-                                             positions, moe=False, ctx=ctx,
-                                             kv_valid=kv_valid)
-            cache = {"k": ks, "v": vs, "pos": jnp.int32(s)}
-        elif cfg.family == "moe":
-            cache = {}
-            if cfg.first_k_dense:
-                x, dk, dv = _dense_prefill_stack(cfg, params["dense_layers"],
-                                                 x, positions, moe=False,
-                                                 kv_valid=kv_valid)
-                cache.update({"dk": dk, "dv": dv})
-            x, ks, vs = _dense_prefill_stack(cfg, params["layers"], x,
-                                             positions, moe=True, ctx=ctx,
-                                             kv_valid=kv_valid)
-            cache.update({"k": ks, "v": vs, "pos": jnp.int32(s)})
+        if cfg.family in ("dense", "vlm", "moe"):
+            cache = {"pos": jnp.int32(s)}
+            if "dense_layers" in params:   # moe's leading dense layers
+                x, cache["dk"], cache["dv"] = _dense_prefill_stack(
+                    cfg, params["dense_layers"], x, positions,
+                    kv_valid=kv_valid)
+            x, cache["k"], cache["v"] = _dense_prefill_stack(
+                cfg, params["layers"], x, positions, ctx=ctx,
+                kv_valid=kv_valid)
         elif cfg.family == "ssm":
             L, b_, d = cfg.n_layers, x.shape[0], cfg.d_model
             h = d // cfg.rwkv_head_dim
@@ -667,13 +644,21 @@ def build_model(cfg: ModelConfig) -> Model:
 
     def decode_step(params, cache, tokens, ctx: Optional[DistCtx] = None,
                     active=None):
-        """tokens: (B, 1). Returns (logits (B,1,V) f32, new cache).
+        """tokens: (B, Q). Returns (logits (B,Q,V) f32, new cache).
 
         cache["pos"] may be a scalar (lockstep decode) or a (B,) vector
-        (slot scheduler: every row at its own offset). active: optional
-        (B,) bool of the rows whose logits the caller uses; the dense
-        stacks' attention may skip the others' cache lines, and their
-        logits are then meaningless (T.attn_block_decode)."""
+        (slot scheduler: every row at its own offset). Q > 1 (attention
+        stacks, per-row pos) decodes Q positions per row in one forward:
+        row b's tokens sit at pos[b]..pos[b]+Q-1 and pos advances by Q.
+        For dense and vlm, whose FFN sees each token alone, logits[:, j]
+        is what the j-th of Q one-token calls gives, up to the rounding of
+        the vocab projection. The speculative verify (serve/spec.py) is
+        this call at Q = k+1; its caller rolls pos back to the last
+        accepted line.
+        active: optional (B,) bool of the rows whose logits the caller
+        uses; the dense stacks' attention may skip the others' cache
+        lines, and their logits are then meaningless
+        (T.attn_block_decode)."""
         x = embed(tokens, params["embed"])
         if cfg.family == "encdec":
             pe = params["dec_pos"][cache["pos"]].astype(x.dtype)
@@ -681,20 +666,22 @@ def build_model(cfg: ModelConfig) -> Model:
             x, cache = _whisper_dec_stack(cfg, params["dec_layers"], x, None,
                                           None, remat="none", cache=cache,
                                           decode=True, ctx=ctx)
-        elif cfg.family in ("dense", "vlm"):
-            x, cache = _dense_decode_stack(cfg, params["layers"], x, cache,
-                                           ctx=ctx, active=active)
-        elif cfg.family == "moe":
+        elif cfg.family in ("dense", "vlm", "moe"):
             pos = cache["pos"]
-            if cfg.first_k_dense:
-                dsub = {"k": cache["dk"], "v": cache["dv"], "pos": pos}
-                x, dsub = _dense_decode_stack(cfg, params["dense_layers"], x,
-                                              dsub, ctx=ctx, active=active)
-                cache = {**cache, "dk": dsub["k"], "dv": dsub["v"]}
-            sub = {"k": cache["k"], "v": cache["v"], "pos": pos}
-            x, sub = _dense_decode_stack(cfg, params["layers"], x, sub,
-                                         ctx=ctx, active=active)
-            cache = {**cache, "k": sub["k"], "v": sub["v"], "pos": pos + 1}
+
+            def attend(lp, h, ks, vs, i):
+                return T.attn_block_decode(lp, h, cfg, cache_k=ks,
+                                           cache_v=vs, layer=i, pos=pos,
+                                           ctx=ctx, active=active)
+
+            cache = dict(cache)
+            if "dense_layers" in params:   # moe's leading dense layers
+                x, cache["dk"], cache["dv"] = _cached_stack(
+                    cfg, params["dense_layers"], x, cache["dk"], cache["dv"],
+                    attend, ctx)
+            x, cache["k"], cache["v"] = _cached_stack(
+                cfg, params["layers"], x, cache["k"], cache["v"], attend, ctx)
+            cache["pos"] = pos + tokens.shape[1]
         elif cfg.family == "ssm":
             x, cache = _rwkv_stack(cfg, params["layers"], x, cache,
                                    decode=True)
@@ -705,42 +692,6 @@ def build_model(cfg: ModelConfig) -> Model:
         else:
             raise ValueError(cfg.family)
         return _logits(params, x), cache
-
-    # ---- speculative verify (multi-position decode) --------------------------
-
-    def decode_verify(params, cache, tokens, ctx: Optional[DistCtx] = None):
-        """tokens: (B, Q) — per row, the last committed token followed by
-        Q-1 draft candidates. Returns (logits (B,Q,V) f32, new cache):
-        logits[:, j] is the target distribution AFTER consuming tokens[:, j],
-        exactly what Q sequential decode_step calls would have produced.
-
-        cache["pos"] must be the (B,) per-row slot-scheduler layout; row
-        writes land at pos..pos+Q-1 and pos advances by Q (the speculated
-        tip). The caller rolls pos back to the last ACCEPTED line after the
-        accept decision — see serve/spec.py for the contract."""
-        assert cfg.family == "dense", (
-            "decode_verify is only defined for pure-attention decoder "
-            f"stacks (position-batchable step): {cfg.family}")
-        x = embed(tokens, params["embed"])
-        pos = cache["pos"]
-
-        def body(carry, inp):
-            x, ks, vs = carry
-            lp, i = inp
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, (ks, vs) = T.attn_block_decode_k(lp["attn"], h, cfg,
-                                                cache_k=ks, cache_v=vs,
-                                                layer=i, pos=pos)
-            x = x + a
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-            return (x + f, ks, vs), None
-
-        (x, ks, vs), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["layers"], _layer_ids(cache["k"])))
-        new_cache = {"k": ks, "v": vs, "pos": pos + tokens.shape[1]}
-        return _logits(params, x), new_cache
 
     # ---- slot refill (continuous batching) -----------------------------------
 
@@ -805,21 +756,13 @@ def build_model(cfg: ModelConfig) -> Model:
         s = x.shape[1]
         positions = start + jnp.arange(s)
 
-        def body(carry, inp):
-            x, ks, vs = carry
-            lp, i = inp
-            h = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            a, (ks, vs) = T.attn_block_continue(
-                lp["attn"], h, cfg, cache_k=ks, cache_v=vs, layer=i,
-                slot=slot, start=start, positions=positions, ctx=ctx)
-            x = x + a
-            h = rms_norm(x, lp["ln2"], cfg.norm_eps)
-            f = swiglu(h, lp["ffn"]["wi"], lp["ffn"]["wg"], lp["ffn"]["wo"])
-            return (x + f, ks, vs), None
+        def attend(lp, h, ks, vs, i):
+            return T.attn_block_continue(
+                lp, h, cfg, cache_k=ks, cache_v=vs, layer=i, slot=slot,
+                start=start, positions=positions, ctx=ctx)
 
-        (x, ks, vs), _ = jax.lax.scan(
-            body, (x, cache["k"], cache["v"]),
-            (params["layers"], _layer_ids(cache["k"])))
+        x, ks, vs = _cached_stack(cfg, params["layers"], x, cache["k"],
+                                  cache["v"], attend, ctx)
         new_cache = {"k": ks, "v": vs,
                      "pos": cache["pos"].at[slot].set(
                          jnp.asarray(start + n_real, cache["pos"].dtype))}
@@ -839,5 +782,4 @@ def build_model(cfg: ModelConfig) -> Model:
         cache_axes=functools.partial(cache_logical_axes, cfg),
         prefill_continue=(prefill_continue if cfg.family == "dense"
                           else None),
-        decode_verify=(decode_verify if cfg.family == "dense" else None),
     )

@@ -32,8 +32,8 @@ from repro.models import attention as attn_lib
 from repro.models import mamba as mamba_lib
 from repro.models import rwkv6 as rwkv_lib
 from repro.models.layers import (
-    PARAM_DTYPE, DistCtx, ParamBuilder, apply_rope, embed, isolate_exact_tp,
-    matmul, matmul_rp, swiglu,
+    PARAM_DTYPE, DistCtx, ParamBuilder, apply_rope, isolate_exact_tp, matmul,
+    matmul_rp,
 )
 
 PyTree = Any
@@ -310,25 +310,33 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
                       pos, window=0, rope=True,
                       ctx: Optional[DistCtx] = None, ring=False,
                       active=None):
-    """One-token attention against layer `layer` of the stacked caches
-    cache_k/v: (n_layers,B,L,KvH,Hd). Returns (out, (cache_k, cache_v))
-    with this token's K/V line written.
+    """Attention of Q = x.shape[1] new tokens a row against layer `layer`
+    of the stacked caches cache_k/v: (n_layers,B,L,KvH,Hd). Returns (out,
+    (cache_k, cache_v)) with the new tokens' K/V lines written.
 
-    pos is the write position — a scalar shared by all rows (the classic
-    lockstep decode, one dynamic_update_slice) or a (B,) vector when every
-    batch row is at its own offset (the serve engine's slot scheduler,
-    where refilled slots join mid-flight): each row's line then lands at
-    its own position (write_kv_lines). active: optional (B,) bool of the
-    rows whose output is used; where the live-line kernel runs
-    (reads_live_lines), the other rows read no line and get zeros."""
-    b_, s, _ = x.shape
-    assert s == 1
+    pos is the write position of each row's first new token — a scalar
+    shared by all rows (the classic lockstep decode, one
+    dynamic_update_slice) or a (B,) vector when every batch row is at its
+    own offset (the serve engine's slot scheduler, where refilled slots
+    join mid-flight): row b's lines then land at pos[b]..pos[b]+Q-1
+    (write_kv_lines). active: optional (B,) bool of the rows whose output
+    is used; where the live-line kernel runs (reads_live_lines), the other
+    rows read no line and get zeros.
+
+    Q > 1 (per-row pos, no ring) is the speculative verify (serve/spec.py):
+    one multi-query causal attention in which token j sees the lines up to
+    pos+j, bit-identical to Q one-token calls. Lines of rejected
+    candidates stay beyond the rolled-back position, masked, and are
+    overwritten by the step that first reaches them."""
+    b_, qn, _ = x.shape
     per_row = jnp.ndim(pos) == 1
+    assert qn == 1 or (per_row and not ring), (qn, per_row, ring)
     q, k, v = _qkv(lp, x, cfg)
     if rope and cfg.rope_theta:
-        # scalar pos -> one shared position; vector pos -> (B,1,1) so the
+        # scalar pos -> one shared position; vector pos -> (B,1,Q) so the
         # angle table broadcasts over heads per row
-        pvec = pos[:, None, None] if per_row else jnp.full((1,), pos, jnp.int32)
+        pvec = (pos[:, None] + jnp.arange(qn))[:, None] if per_row else \
+            jnp.full((1,), pos, jnp.int32)
         q = apply_rope(q.swapaxes(1, 2), pvec, cfg.rope_theta).swapaxes(1, 2)
         k = apply_rope(k.swapaxes(1, 2), pvec, cfg.rope_theta).swapaxes(1, 2)
     lcache = cache_k.shape[2]
@@ -340,8 +348,11 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
         at = (layer, 0, slot, 0, 0)
         cache_k = jax.lax.dynamic_update_slice(cache_k, k[None], at)
         cache_v = jax.lax.dynamic_update_slice(cache_v, v[None], at)
-    cache_len = pos + 1
-    if reads_live_lines(per_row, window, ring):
+    cache_len = pos + qn
+    if qn > 1:
+        out = _attend(attn_lib.decode_attention_multi, q, cache_k[layer],
+                      cache_v[layer], cache_len, window=window)
+    elif reads_live_lines(per_row, window, ring):
         # the kernel takes the stacked caches whole: a slice of one layer
         # would itself be a read of every line
         lens = cache_len if active is None else \
@@ -361,35 +372,6 @@ def attn_block_decode(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
         else:
             out = _attend(attn_lib.decode_attention, q, ck, cv, cache_len,
                           window=window)
-    out = matmul_rp(out.reshape(b_, 1, -1), lp["wo"])
-    return out, (cache_k, cache_v)
-
-
-def attn_block_decode_k(lp, x, cfg: ModelConfig, *, cache_k, cache_v, layer,
-                        pos, window=0, rope=True):
-    """Q-token verify attention against layer `layer` of the stacked
-    caches (speculative decoding — serve/spec.py). x: (B,Q,D) holds the
-    Q=k+1 candidate tokens per row; pos: (B,) per-row write offset of the
-    FIRST candidate (identical to the plain decode write position, so a
-    spec round that accepts zero drafts writes the same line plain decode
-    would have).
-
-    All Q K/V lines land at pos..pos+Q-1 (write_kv_lines), then one
-    multi-query causal attention where candidate j sees cache positions
-    <= pos+j. Rejected candidates' lines stay in the buffer beyond the
-    rolled-back position; they are invisible (cache_len masking) and are
-    overwritten in the step that first reaches them (write-at-pos precedes
-    the mask that includes pos)."""
-    b_, qn, _ = x.shape
-    q, k, v = _qkv(lp, x, cfg)
-    pvec = pos[:, None] + jnp.arange(qn)[None, :]          # (B,Q) absolute
-    if rope and cfg.rope_theta:
-        q = apply_rope(q.swapaxes(1, 2), pvec[:, None], cfg.rope_theta).swapaxes(1, 2)
-        k = apply_rope(k.swapaxes(1, 2), pvec[:, None], cfg.rope_theta).swapaxes(1, 2)
-    cache_k = write_kv_lines(cache_k, layer, pos, k)
-    cache_v = write_kv_lines(cache_v, layer, pos, v)
-    out = attn_lib.decode_attention_multi(q, cache_k[layer], cache_v[layer],
-                                          pos + qn, window=window)
     out = matmul_rp(out.reshape(b_, qn, -1), lp["wo"])
     return out, (cache_k, cache_v)
 

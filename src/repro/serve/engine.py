@@ -254,9 +254,9 @@ class ServeEngine:
         # speculative decoding (serve/spec.py): spec_k > 0 pairs the target
         # with a small draft model that proposes spec_k candidates per
         # active slot each round, verified by ONE (spec_k+1)-position
-        # target forward (Model.decode_verify). The accept loop and the
-        # per-row position rollback are host-managed like the page pool,
-        # so spec is gated off the TP mesh path too.
+        # target forward (Model.decode_step at Q = spec_k+1). The accept
+        # loop and the per-row position rollback are host-managed like the
+        # page pool, so spec is gated off the TP mesh path too.
         self.spec_k = spec_k
         self.draft_cfg = draft_cfg
         self.draft_params = draft_params
@@ -271,6 +271,10 @@ class ServeEngine:
             if draft_cfg is None or draft_params is None:
                 raise ValueError("spec_k>0 requires draft_cfg= and "
                                  "draft_params=")
+            if cfg.family != "dense":
+                raise ValueError(
+                    "target must be a dense decoder (its one-forward verify "
+                    f"of spec_k+1 positions per row): {cfg.family!r}")
             if draft_cfg.family != "dense":
                 raise ValueError("draft model must be a dense decoder "
                                  f"(per-row K/V rollback): {draft_cfg.family!r}")
@@ -388,11 +392,6 @@ class ServeEngine:
             self._prefill_cont = jax.jit(_prefill_cont, donate_argnums=1)
 
         if spec_k:
-            if self.model.decode_verify is None:
-                raise ValueError(
-                    "target family has no multi-position decode_verify "
-                    f"entry (spec_k>0 needs one): {cfg.family!r}")
-
             def _draft_decode(p, c, t, active):
                 logits, new = self.draft_model.decode_step(p, c, t, None,
                                                            active=active)
@@ -405,8 +404,9 @@ class ServeEngine:
 
             def _verify_masked(p, c, t, active):
                 # the (spec_k+1)-position verify forward; done-row masking
-                # holds finished slots' pos exactly like _decode_masked
-                logits, new = self.model.decode_verify(p, c, t, self._ctx)
+                # holds finished slots' pos exactly like _decode_masked.
+                # spec is refused on a mesh, so no exact_tp_scope is needed
+                logits, new = self.model.decode_step(p, c, t, self._ctx)
                 new["pos"] = jnp.where(active, new["pos"], c["pos"])
                 return logits, new
 

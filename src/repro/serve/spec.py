@@ -6,10 +6,10 @@ draft model. Each spec round, per active slot:
   propose  the draft runs spec_k sequential decode steps from the last
            committed token, sampling candidates d_0..d_{k-1} from its own
            distribution (greedy rows take the draft argmax);
-  verify   ONE (k+1)-position target forward (Model.decode_verify) over
-           [cur, d_0..d_{k-1}] yields the target distribution after every
-           candidate — logits[j] judges d_j, logits[k] is the bonus
-           distribution past a full accept;
+  verify   ONE (k+1)-position target forward (Model.decode_step at
+           Q=k+1) over [cur, d_0..d_{k-1}] yields the target distribution
+           after every candidate — logits[j] judges d_j, logits[k] is
+           the bonus distribution past a full accept;
   accept   host-side (this module). Greedy (temperature 0): accept the
            longest prefix where d_j == argmax(logits[j]); the first
            mismatch emits the target argmax as a CORRECTION token, a full

@@ -107,6 +107,60 @@ def test_inplace_write_matches_one_hot_select(small, case):
         np.testing.assert_array_equal(np.asarray(cv), np.asarray(ref_v))
 
 
+def _q_token_rounds(model, params, step, qn):
+    """Two rounds of a (B, Q) decode_step, each beside Q one-token
+    decode_step calls from the same cache; the second round starts row 1
+    from a held position, as done-row masking leaves it, over the lines
+    the first round wrote there. Yields (Q-token, one-token) pairs of
+    (logits, cache)."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    cache = model.init_cache(ROWS, LINES)
+    shape = cache["k"].shape
+    cache["k"] = jax.random.normal(keys[0], shape, cache["k"].dtype)
+    cache["v"] = jax.random.normal(keys[1], shape, cache["v"].dtype)
+    cache["pos"] = jnp.asarray([0, 5, LINES - 2 * qn, 3], jnp.int32)
+    active = jnp.asarray([True, False, True, True])
+    for r in range(2):
+        toks = jax.random.randint(keys[2 + r], (ROWS, qn), 0, 128)
+        seq, ones = cache, []
+        for j in range(qn):
+            lg, seq = step(params, seq, toks[:, j:j + 1])
+            ones.append(lg)
+        logits, cache = step(params, cache, toks)
+        yield (logits, cache), (jnp.concatenate(ones, 1), seq)
+        cache = {**cache, "pos": jnp.where(active, cache["pos"],
+                                           cache["pos"] - qn)}
+
+
+@pytest.mark.parametrize("qn", [2, 4])
+def test_q_token_decode_matches_q_one_token_steps(small, qn, monkeypatch):
+    """decode_step on (B, Q) tokens from per-row positions (the verify of
+    a speculative round) gives bit for bit the final hidden states and
+    the caches of Q one-token decode_step calls. The logits differ only by
+    the rounding of the f32 vocab projection, whose CPU dot sums in
+    another order at B*Q rows than at B rows; their argmax is the same."""
+    cfg, params = small
+    model = build_model(cfg)
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t))
+    for (lg, cache), (want_lg, want) in _q_token_rounds(model, params, step,
+                                                        qn):
+        np.testing.assert_allclose(np.asarray(lg), np.asarray(want_lg),
+                                   rtol=0, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(lg.argmax(-1)),
+                                      np.asarray(want_lg.argmax(-1)))
+        for name in ("k", "v", "pos"):
+            np.testing.assert_array_equal(np.asarray(cache[name]),
+                                          np.asarray(want[name]))
+    # the vocab projection as the identity: logits are the final hidden
+    # states, which must match bit for bit
+    from repro.models import registry
+    monkeypatch.setattr(registry, "lm_logits",
+                        lambda x, table: x.astype(jnp.float32))
+    step = jax.jit(lambda p, c, t: model.decode_step(p, c, t))
+    for (h, _), (want_h, _) in _q_token_rounds(model, params, step, qn):
+        np.testing.assert_array_equal(np.asarray(h), np.asarray(want_h))
+
+
 def _requests(prompts, max_new=6):
     return [Request(rid=i, prompt=np.asarray(p, np.int32),
                     max_new_tokens=max_new) for i, p in enumerate(prompts)]

@@ -134,6 +134,11 @@ def test_spec_rejects_mesh_and_requires_draft(qwen_pair):
     with pytest.raises(ValueError, match="vocab"):
         ServeEngine(cfg, params, draft_cfg=bad, draft_params=bad_params,
                     spec_k=2)
+    moe = reduce_config(get_config("deepseek-moe-16b"), layers=2,
+                        d_model=64, vocab=VOCAB)
+    with pytest.raises(ValueError, match="target must be a dense"):
+        ServeEngine(moe, None, draft_cfg=dcfg, draft_params=dparams,
+                    spec_k=2)
 
 
 def test_spec_composes_with_paged_cache(qwen_pair):
@@ -268,7 +273,8 @@ def test_verify_kernel_int8_pool_matches_dequant_ref(qlen):
 def test_multi_query_attention_matches_sequential_static_cache():
     """The static-cache side of the verify route: decode_attention_multi
     over Q candidate lines equals Q sequential decode_attention calls —
-    the identity that makes decode_verify bit-exact vs decode_step."""
+    the identity that makes a Q-token decode_step (the verify) bit-exact
+    vs Q one-token decode_step calls."""
     from repro.models import attention as attn_lib
     b, qn, h, hd, length = 2, 3, 4, 16, 24
     ks = jax.random.split(jax.random.PRNGKey(2), 3)

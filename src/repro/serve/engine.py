@@ -23,6 +23,15 @@ advance). Finished slots are masked: their pos is held so their cache rows
 stop growing, and their (discarded) sample comes from a constant dummy
 lane. Greedy (temperature=0) rows take argmax.
 
+Decode steps run one ahead of the host. The sampled tokens stay on the
+device as the next step's input, an admission's first token is sampled
+there and written into them at its slot, and which rows decode next
+follows from the host's counts alone (no request stops on a token). So a
+round enqueues the next decode step before it waits for this step's
+tokens, and the device computes while the host pulls them, books them
+and returns to its driver. Speculative rounds keep their synchronous
+loop: their accept decision needs the tokens on the host.
+
 Per-request latency/throughput stats (queue wait, TTFT, decode steps,
 tokens/s) and engine aggregates (total decode steps, slot occupancy) are
 collected on every run — `run(..., collect_stats=True)` returns them, and
@@ -102,7 +111,7 @@ class RequestStats:
     prompt_len: int
     new_tokens: int
     queue_wait_s: float           # enqueue -> admitted into a slot
-    ttft_s: float                 # enqueue -> first token sampled
+    ttft_s: float                 # enqueue -> first token on the host
     decode_steps: int             # batched decode steps this request rode
     total_s: float                # enqueue -> finished
     tok_per_s: float              # new_tokens / (finish - admit)
@@ -113,12 +122,27 @@ class _Slot:
     rid: int
     temperature: float
     remaining: int                # new tokens still to generate
-    n_gen: int                    # tokens generated so far (rng fold index)
+    n_gen: int                    # tokens on the host so far
     prompt_len: int
     t_enqueue: float
     t_admit: float
     t_first: float
     decode_steps: int = 0
+    queued: int = 0               # tokens sampled on the device, not yet
+                                  # on the host (n_gen + queued is the
+                                  # next token's rng fold index)
+
+
+@dataclasses.dataclass
+class _Round:
+    """A batched decode step on the device whose tokens are not yet on
+    the host: each row's slot index and the _Slot it held at enqueue (a
+    row evicted since is skipped when the tokens arrive), the K/V lines
+    each row's decode reads, and whether the step was enqueued while the
+    previous step's tokens were still on the device."""
+    rows: List[Tuple[int, _Slot]]
+    lines: Dict[int, int]
+    overlapped: bool
 
 
 @dataclasses.dataclass
@@ -126,12 +150,12 @@ class StepReport:
     """What one ServeEngine.step() round did — the router's per-tick feed.
 
     admitted:    rids prefilled into a slot this round (their first token
-                 was sampled during admission)
-    finished:    rids whose last token was produced this round (including
-                 degenerate max_new_tokens<1 requests, which finish
-                 without ever occupying a slot)
-    decoded:     occupied rows in this round's batched decode step (0 when
-                 the decode was skipped because nothing was occupied)
+                 is on the host when step() returns)
+    finished:    rids whose last token reached the host this round
+                 (including degenerate max_new_tokens<1 requests, which
+                 finish without ever occupying a slot)
+    decoded:     rows of the batched decode step whose tokens this round
+                 brought to the host (0 when no decode step was due)
     queue_depth: requests still waiting after this round's admissions
     """
     admitted: List[int]
@@ -293,6 +317,7 @@ class ServeEngine:
         # idle/queue_depth/active_count are safe to read before the first
         # reset.
         self._cache = None
+        self._inflight: Optional[_Round] = None
         self._queue: deque = deque()
         self._slots: List[Optional[_Slot]] = [None] * max_batch
 
@@ -381,6 +406,18 @@ class ServeEngine:
                 out_shardings=(sp.replicated, sp.cache), donate_argnums=1)
         self._sample = jax.jit(self._sample_batch_impl)
 
+        # the plain round's sampler: its tokens are the next step's input
+        # and each decoded row's token index advances on the device
+        def _sample_next(logits, temps, key, rids, ngens, active):
+            toks = self._sample_batch_impl(logits, temps, key, rids, ngens)
+            return toks[:, None], ngens + active.astype(jnp.int32)
+
+        self._sample_next = jax.jit(_sample_next)
+        # an admission's first token, written into the token rows at its
+        # slot; and a sync-started round's first tokens beside its own
+        self._place = jax.jit(lambda toks, i, t: toks.at[i, 0].set(t[0]))
+        self._stack = jax.jit(lambda a, b: jnp.concatenate([a, b], axis=1))
+
         # suffix prefill for prefix-reuse admissions (dense family only —
         # Model.prefill_continue is None elsewhere and hits never occur)
         self._prefill_cont = None
@@ -457,18 +494,18 @@ class ServeEngine:
         sampled = jax.vmap(draw)(rids, ngens, lg, temps).astype(jnp.int32)
         return jnp.where(temps > 0, sampled, greedy)
 
-    def _sample_rows(self, logits, slots: List[Optional[_Slot]]) -> np.ndarray:
-        """Sample every row and pull the tokens to the host, a
-        device-to-host round trip (span `serve.sample`)."""
-        with jax.profiler.TraceAnnotation(
-                "serve.sample", rows=sum(s is not None for s in slots)):
-            temps = np.array([s.temperature if s else 0.0 for s in slots],
-                             np.float32)
-            rids = np.array([s.rid if s else -1 for s in slots], np.int32)
-            ngens = np.array([s.n_gen if s else 0 for s in slots], np.int32)
-            return np.asarray(self._sample(
-                logits, jnp.asarray(temps), self.rng, jnp.asarray(rids),
-                jnp.asarray(ngens)))
+    def _sample_rows(self, toks: jax.Array, rows: int) -> np.ndarray:
+        """Pull sampled tokens to the host: a round's one wait for
+        tokens, a device-to-host round trip (span `serve.sample`, with
+        the number of tokens it brings)."""
+        with jax.profiler.TraceAnnotation("serve.sample", rows=rows):
+            return np.asarray(toks)
+
+    def _put(self, *xs: np.ndarray):
+        """Host arrays onto the device in one call (replicated on a mesh,
+        where the programs take them so)."""
+        return jax.device_put(
+            xs, None if self._specs is None else self._specs.replicated)
 
     # ------------------------------------------------------------ admission
 
@@ -534,10 +571,12 @@ class ServeEngine:
                 for did, pb in sorted(params_b.items())]
 
     def _admit(self, cache, slot_idx: int, r: Request, t_enqueue: float):
-        """Prefill r into slot_idx's cache lines; returns
-        (new cache, slot state, first sampled token). The span
-        `serve.admit` covers the admission up to the first token on the
-        host; its args tie it to the request and its prefill bucket."""
+        """Prefill r into slot_idx's cache lines and sample its first
+        token on the device; returns (new cache, slot state, the (1,)
+        first token). Nothing here waits on the device: the token reaches
+        the host with its round's. The span `serve.admit` covers the
+        admission's enqueue; its args tie it to the request and its
+        prefill bucket."""
         with jax.profiler.TraceAnnotation("serve.admit", rid=r.rid,
                                           prompt_len=len(r.prompt)) as span:
             plen = len(r.prompt)
@@ -611,11 +650,11 @@ class ServeEngine:
             slot = _Slot(rid=r.rid, temperature=r.temperature,
                          remaining=r.max_new_tokens, n_gen=0,
                          prompt_len=plen, t_enqueue=t_enqueue,
-                         t_admit=t_admit, t_first=0.0)
-            first = int(self._sample_rows(logits, [slot])[0])
-            slot.t_first = time.perf_counter()
-            slot.n_gen = 1
-            slot.remaining -= 1
+                         t_admit=t_admit, t_first=0.0, queued=1)
+            temps, rids, ngens = self._put(np.float32([r.temperature]),
+                                           np.int32([r.rid]),
+                                           np.zeros(1, np.int32))
+            first = self._sample(logits, temps, self.rng, rids, ngens)
             return cache, slot, first
 
     # ------------------------------------------------------------ scheduler
@@ -638,7 +677,11 @@ class ServeEngine:
         self._per_req: Dict[int, RequestStats] = {}
         self._slots: List[Optional[_Slot]] = [None] * self.max_batch
         # the last run's device state goes before the next is allocated:
-        # two caches never live at once
+        # two caches never live at once (a decode step still in flight is
+        # let finish, and its tokens are discarded)
+        if self._inflight is not None:
+            jax.block_until_ready(self._cache)
+        self._inflight = None
         self._cache = self._draft_cache = self._kv = None
         self._cache = self._fresh_cache()
         if self.kv_page_size:
@@ -656,8 +699,17 @@ class ServeEngine:
         self._spec = {"proposed": 0, "accepted": 0, "rejected": 0,
                       "bonus": 0, "tokens_emitted": 0, "verify_steps": 0,
                       "draft_steps": 0}
+        # the spec rounds' host copy of each row's last token
         self._cur = np.zeros((self.max_batch, 1), np.int32)
+        # the plain rounds' device state: the next decode step's input
+        # tokens and token indices, and the rows (slot, _Slot) whose mask,
+        # temperatures and request ids are on the device
+        self._tok, self._ngens = self._put(
+            np.zeros((self.max_batch, 1), np.int32),
+            np.zeros(self.max_batch, np.int32))
+        self._rows_on_device: Optional[List[Tuple[int, _Slot]]] = None
         self._n_steps = 0          # global batched decode steps
+        self._rounds_overlapped = 0
         self._n_prefills = 0
         self._n_prefill_tokens = 0
         self._n_prefill_bucket_tokens = 0
@@ -669,7 +721,9 @@ class ServeEngine:
 
     @property
     def idle(self) -> bool:
-        """True when nothing is queued and every slot is free."""
+        """True when nothing is queued and every slot is free. A slot is
+        held until its request's last token is on the host, so a decode
+        step in flight keeps the engine busy."""
         return not self._queue and all(s is None for s in self._slots)
 
     @property
@@ -732,10 +786,14 @@ class ServeEngine:
     def step(self) -> StepReport:
         """One scheduler round: refill every free slot from the queue
         (each free slot index gets at most one admission attempt per
-        round), then run one batched decode step over the occupied slots.
-        Returns a StepReport; with nothing occupied after admission the
-        decode is skipped (decoded=0). The span `serve.round` covers the
-        round, with the round's admissions and decoded rows as args."""
+        round), then bring one batched decode step's tokens to the host.
+        That step is the one a previous round enqueued ahead, or, with
+        none in flight, one enqueued now over the occupied slots; the
+        round enqueues the next step over the rows that stay active
+        before it waits for the tokens. Returns a StepReport; with no
+        decode step due the decode is skipped (decoded=0). The span
+        `serve.round` covers the round, with the round's admissions and
+        decoded rows as args."""
         with jax.profiler.TraceAnnotation("serve.round") as span:
             rep = self._round()
             span.set_metadata(admitted=len(rep.admitted), rows=rep.decoded)
@@ -743,7 +801,9 @@ class ServeEngine:
 
     def _round(self) -> StepReport:
         admitted: List[int] = []
-        finished: List[int] = []
+        # slots whose last token finalize() brought to the host
+        finished: List[int] = self._finish_done()
+        firsts: List[int] = []
         for i in range(self.max_batch):
             if self._slots[i] is None and self._queue:
                 r = self._queue.popleft()
@@ -757,60 +817,139 @@ class ServeEngine:
                     continue
                 self._cache, slot, first = self._admit(
                     self._cache, i, r, self._t_enq[r.rid])
+                self._tok = self._place(self._tok, np.int32(i), first)
                 self._n_prefills += 1
-                self._out[r.rid].append(first)
-                self._cur[i, 0] = first
                 self._slots[i] = slot
                 admitted.append(r.rid)
-                if slot.remaining <= 0:      # max_new_tokens == 1
-                    finished.append(self._finish(i))
-        if not any(s is not None for s in self._slots):
-            return StepReport(admitted=admitted, finished=finished,
-                              decoded=0, queue_depth=len(self._queue))
+                firsts.append(i)
         if self.spec_k:
+            if firsts:
+                toks = self._sample_rows(self._tok, len(firsts))
+                self._deliver(toks[:, 0], self._held(firsts))
+                self._cur[firsts] = toks[firsts]
+                finished += self._finish_done()
+            if not any(s is not None for s in self._slots):
+                return StepReport(admitted=admitted, finished=finished,
+                                  decoded=0, queue_depth=len(self._queue))
             return self._spec_round(admitted, finished)
-        active = np.array([s is not None for s in self._slots])
+        # the step to bring to the host: the one in flight, unless every
+        # row it decodes was evicted; else one enqueued now
+        rnd, self._inflight = self._inflight, None
+        if rnd is not None and not self._live(rnd):
+            rnd = None
+        pull = self._tok if firsts or rnd is not None else None
+        if rnd is None and self._due():
+            rnd = self._enqueue(overlapped=False)
+            # this step's tokens beside the first tokens it was fed
+            pull = self._tok if pull is None else self._stack(pull,
+                                                              self._tok)
+        if pull is not None:
+            # the transfer is asked for ahead of the next step's enqueue,
+            # so it never queues behind that step
+            pull.copy_to_host_async()
+        if self._due():
+            self._inflight = self._enqueue(overlapped=True)
+        decoded = 0
+        if pull is not None:
+            live = self._live(rnd) if rnd is not None else []
+            toks = self._sample_rows(pull, len(firsts) + len(live))
+            self._deliver(toks[:, 0], self._held(firsts))
+            if live:
+                self._deliver_step(rnd, live, toks[:, -1])
+                decoded = len(live)
+        finished += self._finish_done()
+        return StepReport(admitted=admitted, finished=finished,
+                          decoded=decoded, queue_depth=len(self._queue))
+
+    def _held(self, rows: Iterable[int]) -> List[Tuple[int, _Slot]]:
+        return [(i, self._slots[i]) for i in rows]
+
+    def _live(self, rnd: _Round) -> List[Tuple[int, _Slot]]:
+        """The rows of a step whose slot still holds the same request."""
+        return [(i, s) for i, s in rnd.rows if self._slots[i] is s]
+
+    def _due(self) -> List[int]:
+        """Occupied slots with a token still to sample."""
+        return [i for i, s in enumerate(self._slots)
+                if s is not None and s.remaining > s.queued]
+
+    def _enqueue(self, *, overlapped: bool) -> _Round:
+        """Enqueue one batched decode step and its sampler over the due
+        rows. The input tokens and token indices are the device's own;
+        the mask, temperatures and request ids go to the device only when
+        the rows changed since the last step."""
+        rows = self._held(self._due())
+        prev = self._rows_on_device
+        if prev is None or len(prev) != len(rows) or any(
+                i != j or s is not t for (i, s), (j, t) in zip(prev, rows)):
+            active = np.zeros(self.max_batch, bool)
+            temps = np.zeros(self.max_batch, np.float32)
+            rids = np.full(self.max_batch, -1, np.int32)
+            ngens = np.zeros(self.max_batch, np.int32)
+            for i, s in rows:
+                active[i], temps[i], rids[i] = True, s.temperature, s.rid
+                ngens[i] = s.n_gen + s.queued
+            self._active, self._temps, self._rids, self._ngens = self._put(
+                active, temps, rids, ngens)
+            self._rows_on_device = rows
         with jax.profiler.TraceAnnotation("serve.decode"):
             logits, self._cache = self._decode(self.params, self._cache,
-                                               jnp.asarray(self._cur),
-                                               jnp.asarray(active))
-        self._n_steps += 1
-        self._slot_steps_active += int(active.sum())
-        self._count_kv_lines(self._kv_blk)
-        toks = self._sample_rows(logits, self._slots)
-        for i, s in enumerate(self._slots):
-            if s is None:
-                continue
-            tok = int(toks[i])
-            self._out[s.rid].append(tok)
-            self._cur[i, 0] = tok
+                                               self._tok, self._active)
+            self._tok, self._ngens = self._sample_next(
+                logits, self._temps, self.rng, self._rids, self._ngens,
+                self._active)
+        # a row about to decode holds prompt_len + its sampled tokens
+        # lines, its new one included
+        lines = {i: s.prompt_len + s.n_gen + s.queued for i, s in rows}
+        for _, s in rows:
+            s.queued += 1
+        return _Round(rows=rows, lines=lines, overlapped=overlapped)
+
+    def _deliver(self, toks: np.ndarray, rows: List[Tuple[int, _Slot]],
+                 decode: bool = False) -> None:
+        """Book tokens that reached the host: toks[i] is the next token of
+        the request in slot i (a first token unless `decode`)."""
+        now = time.perf_counter()
+        for i, s in rows:
+            self._out[s.rid].append(int(toks[i]))
             s.n_gen += 1
             s.remaining -= 1
+            s.queued -= 1
+            if not decode:
+                s.t_first = now
+                continue
             s.decode_steps += 1
             if self._kv is not None:
                 # decode growth: allocate pages as the row crosses a
                 # page boundary (the written line is at pos-1; pos covers
                 # prompt_len + n_gen lines)
                 self._kv.grow(s.rid, s.prompt_len + s.n_gen)
-            if s.remaining <= 0:
-                finished.append(self._finish(i))
-        return StepReport(admitted=admitted, finished=finished,
-                          decoded=int(active.sum()),
-                          queue_depth=len(self._queue))
 
-    def _count_kv_lines(self, blk: int) -> None:
-        """Count one decode step's K/V lines a layer: those it reads, each
-        active row's lines rounded up to `blk` (every line of every slot
-        where blk is 0), and those a whole-cache read takes. A row about
-        to decode holds prompt_len + n_gen lines, its new one included."""
+    def _finish_done(self) -> List[int]:
+        return [self._finish(i) for i, s in enumerate(self._slots)
+                if s is not None and s.remaining <= 0]
+
+    def _deliver_step(self, rnd: _Round, live: List[Tuple[int, _Slot]],
+                      toks: np.ndarray) -> None:
+        """Book a decode step whose tokens reached the host, over its rows
+        still live, and count it: its K/V lines a layer are each row's
+        lines rounded up to the live-line kernel's block (every line of
+        every slot where the whole slab is read)."""
+        self._n_steps += 1
+        self._slot_steps_active += len(live)
+        self._rounds_overlapped += rnd.overlapped
+        blk = self._kv_blk
+        self._count_kv_lines(sum(-(-rnd.lines[i] // blk) * blk
+                                 for i, _ in live) if blk else None)
+        self._deliver(toks, live, decode=True)
+
+    def _count_kv_lines(self, read: Optional[int]) -> None:
+        """Count one decode step's K/V lines a layer: `read`, those it
+        reads (every line of every slot where None), and those a
+        whole-cache read takes."""
         full = self.max_batch * self.cache_len
         self._kv_lines_full += full
-        if not blk:
-            self._kv_lines_read += full
-            return
-        self._kv_lines_read += sum(
-            -(-(s.prompt_len + s.n_gen) // blk) * blk
-            for s in self._slots if s is not None)
+        self._kv_lines_read += full if read is None else read
 
     def _spec_round(self, admitted: List[int], finished: List[int]
                     ) -> StepReport:
@@ -870,7 +1009,7 @@ class ServeEngine:
         vlg = np.asarray(vlg, np.float32)
         self._n_steps += 1
         self._slot_steps_active += n_active
-        self._count_kv_lines(0)      # the verify reads every line
+        self._count_kv_lines(None)   # the verify reads every line
         self._spec["verify_steps"] += n_active
         self._spec["draft_steps"] += n_active * (k + 1)
         self._spec["proposed"] += n_active * k
@@ -927,13 +1066,15 @@ class ServeEngine:
         re-queued request restarts from scratch, and the per-request
         fold_in(rid, i) sample keys make the restart token-for-token
         identical to an undisturbed run (the chaos-tier contract).
-        Returns (evicted requests, tokens thrown away). The evicted
-        slots' cache rows need no scrubbing: a freed slot's pos is held
-        (its rows are masked) until the next admission overwrites them —
-        PROVIDED the held pos never overstates the row's committed
-        content. A spec engine evicted mid-verify violates that (device
-        pos sits at the speculated tip), so spec slots roll back to the
-        last ACCEPTED line here."""
+        Returns (evicted requests, tokens thrown away); an evicted row's
+        token of a decode step still in flight is dropped when it
+        arrives. The evicted slots' cache rows need no scrubbing: a freed
+        slot's pos is held (its rows are masked) until the next admission
+        overwrites them. That step in flight may have written one line
+        past the row's committed content, which nothing reads: the
+        admission's prefill sets the row's pos anew. A spec engine
+        evicted mid-verify would leave its pos at the speculated tip, and
+        its slots roll back to the last ACCEPTED line here."""
         target = None if rids is None else set(rids)
         evicted: List[Request] = []
         wasted = 0
@@ -977,7 +1118,14 @@ class ServeEngine:
     def finalize(self) -> Dict[str, Any]:
         """Aggregate this run's counters into the engine-stats dict
         (also stored on last_stats). run() calls it after draining; a
-        stepwise driver calls it when it stops driving the engine."""
+        stepwise driver calls it when it stops driving the engine. A
+        decode step still in flight is brought to the host first and
+        counted here; requests it ends are reported by the next step()."""
+        rnd, self._inflight = self._inflight, None
+        live = self._live(rnd) if rnd is not None else []
+        if live:
+            toks = self._sample_rows(self._tok, len(live))
+            self._deliver_step(rnd, live, toks[:, -1])
         wall = time.perf_counter() - self._t_start
         engine_stats = aggregate_engine_stats(
             self._per_req, n_requests=self._n_submitted,
@@ -1000,6 +1148,9 @@ class ServeEngine:
         # reads would take
         engine_stats["kv_lines_read"] = self._kv_lines_read
         engine_stats["kv_lines_full"] = self._kv_lines_full
+        # decode steps enqueued while the previous step's tokens were
+        # still on the device
+        engine_stats["rounds_overlapped"] = self._rounds_overlapped
         if self._kv is not None:
             # merged outside the pinned schema, like the counters above
             engine_stats["kvcache"] = self._kv.stats()

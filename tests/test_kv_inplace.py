@@ -172,8 +172,9 @@ PROMPTS = [np.arange(20) % 128, np.arange(20) % 128, np.arange(3, 9)]
 @pytest.mark.parametrize("kind", ["plain", "paged", "spec"])
 def test_engine_donates_its_cache_to_each_step(small, kind):
     """After step() no array of the caches the engine held before it is
-    alive, and the run still serves the plain engine's tokens (the paged
-    run takes a prefix hit: restore, then the donated suffix prefill)."""
+    alive, unless the step ran no program and they are still its own,
+    and the run still serves the plain engine's tokens (the paged run
+    takes a prefix hit: restore, then the donated suffix prefill)."""
     cfg, params = small
     kw = {}
     if kind == "paged":
@@ -192,7 +193,11 @@ def test_engine_donates_its_cache_to_each_step(small, kind):
     while not eng.idle:
         held = jax.tree.leaves((eng._cache, eng._draft_cache))
         eng.step()
-        assert all(a.is_deleted() for a in held)
+        # a round that only brings the step in flight to the host runs
+        # no program: the caches it held are still the engine's own
+        now = jax.tree.leaves((eng._cache, eng._draft_cache))
+        assert all(a.is_deleted() for a in held) or all(
+            a is b for a, b in zip(held, now))
     assert eng.outputs == want
     if kind == "paged":
         assert eng.kv.stats()["prefix_hits"] > 0

@@ -40,21 +40,40 @@ def _wave_decode_steps(reqs, max_batch):
 
 def _slot_sim_steps(reqs, max_batch):
     """Pure-python simulation of the slot scheduler's global decode-step
-    count (admit free slots FIFO before every step; a slot leaves when its
-    request's last token is sampled)."""
+    count. A round admits into free slots FIFO (the first token comes
+    from prefill), then brings one decode step's tokens to the host: the
+    step a previous round enqueued ahead, else one enqueued now over the
+    rows with a token still to sample; before that it enqueues the next
+    step over the rows that still have one. A slot leaves when its
+    request's last token is on the host."""
     q = deque(r.max_new_tokens for r in reqs)
-    slots = [None] * max_batch
-    steps = 0
-    while q or any(s is not None for s in slots):
+    left = [0] * max_batch            # tokens not yet on the host
+    sampled = [0] * max_batch         # of those, already sampled
+    ahead, steps = None, 0
+
+    def due():
+        return [i for i in range(max_batch) if left[i] > sampled[i]]
+
+    def enqueue():
+        rows = due()
+        for i in rows:
+            sampled[i] += 1
+        return rows
+
+    while q or any(left):
         for i in range(max_batch):
-            if slots[i] is None and q:
-                rem = q.popleft() - 1          # first token from prefill
-                slots[i] = rem if rem > 0 else None
-        if not any(s is not None for s in slots):
-            continue
-        steps += 1
-        slots = [None if s == 1 else (s - 1 if s is not None else None)
-                 for s in slots]
+            if not left[i] and q:
+                left[i], sampled[i] = q.popleft(), 1
+        rows, ahead = ahead, None
+        if not rows and due():
+            rows = enqueue()
+        if due():
+            ahead = enqueue()
+        for i in range(max_batch):        # the round's tokens arrive
+            n = sampled[i] - (i in (ahead or ()))
+            left[i] -= n
+            sampled[i] -= n
+        steps += bool(rows)
     return steps
 
 

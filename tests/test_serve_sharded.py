@@ -243,7 +243,10 @@ def test_sharded_engine_donates_its_cache_8dev():
         while not eng.idle:
             held = jax.tree.leaves(eng._cache)
             eng.step()
-            assert all(a.is_deleted() for a in held)
+            # a round that only brings the step in flight to the host
+            # runs no program: the cache it held is still the engine's
+            assert all(a.is_deleted() for a in held) or all(
+                a is b for a, b in zip(held, jax.tree.leaves(eng._cache)))
         assert eng.outputs == ref, (eng.outputs, ref)
         print("donated, bit-exact")
     """)
@@ -326,15 +329,23 @@ def test_tp4_gqa4_engine_matches_the_f32_reference():
                           mesh=make_mesh((4,), ("model",)))
         kv = eng._specs.cache["k"].spec
         assert tuple(kv)[3] == "model", kv          # one K/V head per chip
-        # the logits each sampled token came from: (rid, token index)
-        served, sample = {}, eng._sample_rows
-        def record(logits, slots):
-            rows = np.asarray(logits, np.float32).reshape(len(slots), -1)
-            for s, row in zip(slots, rows):
-                if s is not None:
-                    served.setdefault((s.rid, s.n_gen), row)
-            return sample(logits, slots)
-        eng._sample_rows = record
+        # the logits each sampled token came from: (rid, token index),
+        # from the first-token sampler and the decode steps' sampler
+        served, sample, sample_next = {}, eng._sample, eng._sample_next
+        def record(logits, rids, ngens, active=None):
+            rows = np.asarray(logits, np.float32).reshape(len(rids), -1)
+            rids, ngens = np.asarray(rids), np.asarray(ngens)
+            live = rids >= 0 if active is None else np.asarray(active)
+            for r, n, a, row in zip(rids, ngens, live, rows):
+                if a:
+                    served.setdefault((int(r), int(n)), row)
+        def first(logits, temps, key, rids, ngens):
+            record(logits, rids, ngens)
+            return sample(logits, temps, key, rids, ngens)
+        def step(logits, temps, key, rids, ngens, active):
+            record(logits, rids, ngens, active)
+            return sample_next(logits, temps, key, rids, ngens, active)
+        eng._sample, eng._sample_next = first, step
         out = eng.run(mk())
         fc, kd = ref._freeze(CFG), jnp.asarray(ref.key_data(SEED))
         g = ref._gen_globals(fc, kd)
